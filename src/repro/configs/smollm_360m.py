@@ -1,5 +1,5 @@
 """smollm-360m [dense]: 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152
-— llama-arch small [hf:HuggingFaceTB/SmolLM-135M; hf]."""
+— llama-arch small [hf:HuggingFaceTB/SmolLM-360M; hf]."""
 
 from .base import ModelConfig
 

@@ -1055,6 +1055,22 @@ def orthogonal_from_config(cfg: OrthoConfig) -> GradientTransformation:
     return _build(spec.factory(**_method_kwargs(cfg)), cfg)
 
 
+def _pin_gathered(group: GroupSpec) -> bool:
+    """Whether a group's stack is pinned replicated before its shard_map.
+
+    Only a gathered stack (concatenated, reshaped or transposed member
+    leaves) on the CPU backend: the host-platform partitioner has
+    returned wrong values for a concatenate consumed batch-sharded (see
+    ``shard_hints.shard_group_step``). A TPU reshards gathered stacks
+    directly, and pinning them there would be the round trip the sharded
+    schedule exists to avoid."""
+    m0 = group.members[0]
+    simple = (
+        len(group.members) == 1 and not m0.transpose and len(m0.lead) == 1
+    )
+    return not simple and jax.default_backend() == "cpu"
+
+
 def _run_group_step(fn, group: GroupSpec, ops: tuple, out_ndims: tuple):
     """Run one group step, sharded over the DP mesh axes when possible.
 
@@ -1082,17 +1098,8 @@ def _run_group_step(fn, group: GroupSpec, ops: tuple, out_ndims: tuple):
         from ..distributed import shard_hints
     except ImportError:  # pragma: no cover - distributed always ships
         return fn(*ops)
-    m0 = group.members[0]
-    simple = (
-        len(group.members) == 1 and not m0.transpose and len(m0.lead) == 1
-    )
-    # The wrong-values bug lives in the CPU host-platform partitioner
-    # (see shard_hints.shard_group_step); TPU/GPU reshard gathered stacks
-    # directly — pinning them replicated there would be exactly the
-    # round-trip the sharded schedule exists to avoid.
-    pin = (not simple) and jax.default_backend() == "cpu"
     wrapped = shard_hints.shard_group_step(
-        fn, group.batch, out_ndims, pin_inputs=pin
+        fn, group.batch, out_ndims, pin_inputs=_pin_gathered(group)
     )
     if wrapped is None:
         return fn(*ops)
@@ -1134,16 +1141,8 @@ def _run_group_step_tp(fn, group: GroupSpec, n_pad: int, ops: tuple,
         from ..distributed import shard_hints
     except ImportError:  # pragma: no cover - distributed always ships
         return None
-    m0 = group.members[0]
-    simple = (
-        len(group.members) == 1 and not m0.transpose and len(m0.lead) == 1
-    )
-    # Same CPU host-platform concat miscompile workaround as the DP
-    # schedule (shard_hints.shard_group_step): gathered stacks consumed
-    # sharded produce WRONG VALUES off-TPU unless pinned replicated first.
-    pin = (not simple) and jax.default_backend() == "cpu"
     res = shard_hints.shard_group_step_tp(
-        fn, group.batch, n_pad, out_kinds, pin_inputs=pin
+        fn, group.batch, n_pad, out_kinds, pin_inputs=_pin_gathered(group)
     )
     if res is None:
         return None
@@ -1752,7 +1751,22 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
             extras=extras,
         )
 
-    return GradientTransformation(init, update)
+    return GradientTransformation(_full_precision(init), _full_precision(update))
+
+
+def _full_precision(fn):
+    """Trace ``fn`` with fp32 matmuls at full precision: the constraint
+    algebra of every route (jnp stages, oracles, telemetry). XLA's default
+    on a TPU is one bf16 pass, which held the two-stage POGO step 1e-2 to
+    3e-2 from the manifold on a v5e (8e-6 at full precision). On a CPU
+    both are the same fp32 product."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapped
 
 
 # ----------------------------------------------------------------- telemetry

@@ -18,10 +18,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-from .compat import shard_map
 
 
 class EFState(NamedTuple):

@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -105,8 +106,6 @@ def shard_group_step(fn, batch: int, out_ndims, *, pin_inputs: bool = False):
     axes = _batch_axes(_MESH, batch)
     if axes is None:
         return None
-    from .compat import shard_map
-
     mesh = _MESH
 
     def bspec(nd):
@@ -191,8 +190,6 @@ def shard_group_step_tp(fn, batch: int, n: int, out_kinds, *,
     if n % twidth != 0:
         return None
     axes = _batch_axes(_MESH, batch) if batch > 1 else None
-    from .compat import shard_map
-
     mesh = _MESH
 
     def dp_spec(nd):

@@ -7,9 +7,10 @@ the planner times each once and keeps the fastest. Results are cached
 
   * in-process (``PlanCache._mem``) so a key is timed at most once per
     process, and
-  * in a JSON file (``~/.cache/repro_kernels/autotune.json`` by default,
-    override with ``REPRO_AUTOTUNE_CACHE``) so trainer restarts and
-    benchmark runs reuse tuned plans across processes.
+  * in a JSON file (``repro_autotune.json`` in the compile-cache
+    directory of ``repro.compile_cache`` by default, override with
+    ``REPRO_AUTOTUNE_CACHE``) so trainer restarts and benchmark runs
+    reuse tuned plans across processes.
 
 Cache file format (versioned; unknown versions are ignored, corrupt
 files/entries are treated as empty — and NAMED in a RuntimeWarning, so a
@@ -44,12 +45,15 @@ meaningless); ``REPRO_AUTOTUNE=1`` / ``0`` forces either way.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import random
 import tempfile
 import time
 import warnings
 from typing import Callable, Optional
+
+log = logging.getLogger("repro.autotune")
 
 # Process-wide counters, exposed for tests and diagnostics.
 STATS = {
@@ -62,9 +66,9 @@ def default_cache_path() -> str:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "repro_kernels", "autotune.json"
-    )
+    from .. import compile_cache
+
+    return os.path.join(compile_cache.cache_dir(), "repro_autotune.json")
 
 
 _DEVICE_KIND: Optional[str] = None
@@ -339,10 +343,9 @@ def choose(
     autotuning disabled or a single candidate, the first candidate (the
     heuristic default) wins and is cached in-memory only.
 
-    Timing is best-effort, matching the cache philosophy ("an
-    optimization; never fail the step over it"): a candidate that fails
-    to compile or run is skipped, and if every candidate fails the
-    heuristic default wins.
+    A candidate that fails to compile or run is logged with its error
+    and skipped; if every candidate fails, the planner raises — a
+    default plan that cannot compile would only fail later.
     """
     if not candidates:
         raise ValueError(f"no feasible kernel plan candidates for {key}")
@@ -360,19 +363,23 @@ def choose(
         cache.store(key, plan, persist=False)
         return plan
     best, best_t = None, float("inf")
+    errors = []
     for cand in candidates:
         STATS["timing_runs"] += 1
         try:
             t = time_candidate(cand)
-        except Exception:  # noqa: BLE001 - skip uncompilable candidates
+        except Exception as e:  # noqa: BLE001 - logged, then the next one
+            log.warning("autotune %s: candidate %s failed: %s: %s",
+                        key, cand, type(e).__name__, e)
+            errors.append(f"{cand}: {type(e).__name__}: {e}")
             continue
         if t < best_t:
             best, best_t = dict(cand), t
-    if best is None:  # every candidate failed to time: heuristic default
-        plan = dict(candidates[0])
-        plan["source"] = "heuristic"
-        cache.store(key, plan, persist=False)
-        return plan
+    if best is None:
+        raise RuntimeError(
+            f"autotune {key}: no candidate compiled and ran:\n"
+            + "\n".join(errors)
+        )
     best["us_per_matrix"] = best_t * 1e6
     best["source"] = "autotune"
     cache.store(key, best, persist=True)

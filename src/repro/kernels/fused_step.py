@@ -62,7 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pogo_update import _CompilerParams, _phase3_kernel
+from .pogo_update import _phase3_kernel, compiler_params
+from .pogo_update import dot as _dot
 
 Array = jax.Array
 
@@ -72,8 +73,27 @@ _DP = (((2,), (1,)), ((0,), (0,)))  # (bm,p,p)x(bm,p,n)->(bm,p,n); also (p,p)x(p
 N_SCALARS = 8  # eta, lam, post_scale, h0..h4
 
 
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+def _sum_pn(v):
+    """Per-matrix sum of a (bm, p, n) block as (bm, 1, 1): lanes first,
+    then sublanes. One reduction over both axes at once makes the TPU
+    compiler abort (a layout CHECK, not an error) whenever bm > 1."""
+    return jnp.sum(jnp.sum(v, axis=-1, keepdims=True), axis=-2, keepdims=True)
+
+
+# Per-matrix scalars (nu, pv, the vadam scale, dist) cross the kernel
+# boundary as (B, 1, 1) arrays: a block's last two dims then equal the
+# array's (1, 1), which Mosaic accepts for any batch block. A (B, 1)
+# column would need a batch block that is a multiple of 8 or all of B.
+def _col_spec(block_b, index_map):
+    return pl.BlockSpec((block_b, 1, 1), index_map)
+
+
+def _to3(col):
+    return col.reshape(col.shape[0], 1, 1)
+
+
+def _to2(col):
+    return col.reshape(col.shape[0], 1)
 
 
 def _base_stage_whole(scal_ref, g, mu_ref, nu_ref, mu_out, nu_out, base_kind, nesterov):
@@ -92,11 +112,11 @@ def _base_stage_whole(scal_ref, g, mu_ref, nu_ref, mu_out, nu_out, base_kind, ne
     c1, c2 = scal_ref[6], scal_ref[7]
     mu2 = b1 * mu_ref[...].astype(jnp.float32) + (1.0 - b1) * g
     mu_out[...] = mu2.astype(mu_out.dtype)
-    sq = jnp.sum(g * g, axis=(1, 2))  # raw-gradient norm per matrix
-    nu2 = b2 * nu_ref[...].astype(jnp.float32)[:, 0] + (1.0 - b2) * sq
-    nu_out[...] = nu2[:, None].astype(nu_out.dtype)
+    sq = _sum_pn(g * g)  # raw-gradient norm per matrix, (bm, 1, 1)
+    nu2 = b2 * nu_ref[...].astype(jnp.float32) + (1.0 - b2) * sq
+    nu_out[...] = nu2.astype(nu_out.dtype)
     denom = jnp.sqrt(nu2 / c2) + eps
-    return (ps / c1) * mu2 / denom[:, None, None]
+    return (ps / c1) * mu2 / denom
 
 
 def _masked_eye(p_pad: int, p_valid: int):
@@ -111,22 +131,23 @@ def _masked_eye(p_pad: int, p_valid: int):
 
 
 def _residual_dist(w, p_valid: int):
-    """||W - I_p||_F per matrix from a (bm, p_pad, p_pad) gram block."""
+    """||W - I_p||_F per matrix, (bm, 1, 1), from a (bm, p_pad, p_pad)
+    gram block."""
     res = w - _masked_eye(w.shape[-1], p_valid)[None]
-    return jnp.sqrt(jnp.sum(res * res, axis=(1, 2)))
+    return jnp.sqrt(_sum_pn(res * res))
 
 
 def _residual_dist_ragged(w, pv_col):
-    """Per-matrix rectangular mask: ``pv_col`` is the (bm, 1) int32
+    """Per-matrix rectangular mask: ``pv_col`` is the (bm, 1, 1) int32
     valid-row counts of a ragged megagroup block — each matrix subtracts
     the identity on its OWN true rows only (the static ``p_valid``
     diagonal mask, generalized per matrix)."""
     pp = w.shape[-1]
     eye = jnp.eye(pp, dtype=jnp.float32)
     row = jax.lax.broadcasted_iota(jnp.int32, (1, pp, pp), 1)
-    mask = (row < pv_col[:, :, None]).astype(jnp.float32)  # (bm, pp, pp)
+    mask = (row < pv_col).astype(jnp.float32)  # (bm, pp, pp)
     res = w - eye[None] * mask
-    return jnp.sqrt(jnp.sum(res * res, axis=(1, 2)))
+    return jnp.sqrt(_sum_pn(res * res))
 
 
 def _fused_whole_kernel(scal_ref, *refs, method, base_kind, nesterov, p_valid,
@@ -167,9 +188,9 @@ def _fused_whole_kernel(scal_ref, *refs, method, base_kind, nesterov, p_valid,
         o_ref[...] = x2.astype(o_ref.dtype)
         w = _dot(x2, x2, _DN)  # X' still resident: direct gram, zero HBM
     if ragged:
-        dist_ref[...] = _residual_dist_ragged(w, pv_ref[...])[:, None]
+        dist_ref[...] = _residual_dist_ragged(w, pv_ref[...])
     else:
-        dist_ref[...] = _residual_dist(w, p_valid)[:, None]
+        dist_ref[...] = _residual_dist(w, p_valid)
 
 
 def fused_step_whole(
@@ -196,7 +217,7 @@ def fused_step_whole(
     bsz, p, n = x.shape
     assert bsz % block_b == 0, (bsz, block_b)
     mat_spec = pl.BlockSpec((block_b, p, n), lambda i, s: (i, 0, 0))
-    col_spec = pl.BlockSpec((block_b, 1), lambda i, s: (i, 0))
+    col_spec = _col_spec(block_b, lambda i, s: (i, 0, 0))
     in_specs = [mat_spec, mat_spec]
     operands = [x, g]
     out_specs = [mat_spec]
@@ -208,14 +229,14 @@ def fused_step_whole(
         out_shape.append(jax.ShapeDtypeStruct(mu.shape, mu.dtype))
     if base_kind == "vadam":
         in_specs.append(col_spec)
-        operands.append(nu)
+        operands.append(_to3(nu))
         out_specs.append(col_spec)
-        out_shape.append(jax.ShapeDtypeStruct(nu.shape, nu.dtype))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, 1, 1), nu.dtype))
     if pv is not None:
         in_specs.append(col_spec)
-        operands.append(pv)
+        operands.append(_to3(pv))
     out_specs.append(col_spec)
-    out_shape.append(jax.ShapeDtypeStruct((bsz, 1), jnp.float32))
+    out_shape.append(jax.ShapeDtypeStruct((bsz, 1, 1), jnp.float32))
 
     kernel = functools.partial(
         _fused_whole_kernel, method=method, base_kind=base_kind,
@@ -231,13 +252,14 @@ def fused_step_whole(
             out_specs=out_specs,
         ),
         out_shape=out_shape,
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(scal, *operands)
     outs = list(outs)
     x2 = outs.pop(0)
     mu2 = outs.pop(0) if base_kind != "none" else None
-    nu2 = outs.pop(0) if base_kind == "vadam" else None
-    dist = outs.pop(0)
+    nu2 = _to2(outs.pop(0)) if base_kind == "vadam" else None
+    dist = _to2(outs.pop(0))
     return x2, mu2, nu2, dist
 
 
@@ -294,7 +316,7 @@ def _tp_gram_kernel(scal_ref, *refs, base_kind, nesterov):
         mu2 = b1 * mu_ref[...].astype(jnp.float32) + (1.0 - b1) * g
         mu_out[...] = mu2.astype(mu_out.dtype)
         gb = mu2
-        sq_ref[...] = jnp.sum(g * g, axis=(1, 2))[:, None]
+        sq_ref[...] = _sum_pn(g * g)
     gb_ref[...] = gb
     a_ref[...] = _dot(x, x, _DN)
     b_ref[...] = _dot(x, gb, _DN)
@@ -321,7 +343,7 @@ def tp_gram_whole(
     assert bsz % block_b == 0, (bsz, block_b)
     mat_spec = pl.BlockSpec((block_b, p, n), lambda i, s: (i, 0, 0))
     pp_spec = pl.BlockSpec((block_b, p, p), lambda i, s: (i, 0, 0))
-    col_spec = pl.BlockSpec((block_b, 1), lambda i, s: (i, 0))
+    col_spec = _col_spec(block_b, lambda i, s: (i, 0, 0))
     in_specs = [mat_spec, mat_spec]
     operands = [x, g]
     if base_kind != "none":
@@ -336,7 +358,7 @@ def tp_gram_whole(
         out_shape.append(jax.ShapeDtypeStruct(mu.shape, mu.dtype))
     if base_kind == "vadam":
         out_specs.append(col_spec)
-        out_shape.append(jax.ShapeDtypeStruct((bsz, 1), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, 1, 1), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(
             _tp_gram_kernel, base_kind=base_kind, nesterov=nesterov
@@ -348,12 +370,13 @@ def tp_gram_whole(
             out_specs=out_specs,
         ),
         out_shape=out_shape,
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(scal, *operands)
     outs = list(outs)
     a, b, s, gb = outs.pop(0), outs.pop(0), outs.pop(0), outs.pop(0)
     mu2 = outs.pop(0) if base_kind != "none" else None
-    sq = outs.pop(0) if base_kind == "vadam" else None
+    sq = _to2(outs.pop(0)) if base_kind == "vadam" else None
     return a, b, s, gb, mu2, sq
 
 
@@ -379,7 +402,7 @@ def _tp_apply_kernel(scal_ref, *refs, method, base_kind, p_valid, ragged):
     s = s_ref[...]
     geff = gb_ref[...].astype(jnp.float32)
     if base_kind == "vadam":
-        scl = scl_ref[...][:, :, None]  # (bm, 1, 1)
+        scl = scl_ref[...]  # (bm, 1, 1)
         geff = scl * geff
         b = scl * b
         s = (scl * scl) * s
@@ -409,9 +432,9 @@ def _tp_apply_kernel(scal_ref, *refs, method, base_kind, p_valid, ragged):
         fft = rr + lam * (rn + jnp.swapaxes(rn, -1, -2)) + lam * lam * nn
         w = a - 2.0 * eta * lam * (a2 - a) + (eta * eta) * fft
     if ragged:
-        dist_ref[...] = _residual_dist_ragged(w, pv_ref[...])[:, None]
+        dist_ref[...] = _residual_dist_ragged(w, pv_ref[...])
     else:
-        dist_ref[...] = _residual_dist(w, p_valid)[:, None]
+        dist_ref[...] = _residual_dist(w, p_valid)
 
 
 def tp_apply_whole(
@@ -439,19 +462,19 @@ def tp_apply_whole(
     assert bsz % block_b == 0, (bsz, block_b)
     mat_spec = pl.BlockSpec((block_b, p, n), lambda i, s_: (i, 0, 0))
     pp_spec = pl.BlockSpec((block_b, p, p), lambda i, s_: (i, 0, 0))
-    col_spec = pl.BlockSpec((block_b, 1), lambda i, s_: (i, 0))
+    col_spec = _col_spec(block_b, lambda i, s_: (i, 0, 0))
     in_specs = [mat_spec, mat_spec, pp_spec, pp_spec, pp_spec]
     operands = [x, gb, a, b, s]
     if base_kind == "vadam":
         in_specs.append(col_spec)
-        operands.append(scl)
+        operands.append(_to3(scl))
     if pv is not None:
         in_specs.append(col_spec)
-        operands.append(pv)
+        operands.append(_to3(pv))
     out_specs = [mat_spec, col_spec]
     out_shape = [
         jax.ShapeDtypeStruct(x.shape, x.dtype),
-        jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bsz, 1, 1), jnp.float32),
     ]
     x2, dist = pl.pallas_call(
         functools.partial(
@@ -465,9 +488,10 @@ def tp_apply_whole(
             out_specs=out_specs,
         ),
         out_shape=out_shape,
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(scal, *operands)
-    return x2, dist
+    return x2, _to2(dist)
 
 
 # ---------------------------------------------------------------------- tiled
@@ -515,7 +539,7 @@ def _t1_kernel(scal_ref, *refs, base_kind, nesterov):
     a_ref[...] += a_part
     b_ref[...] += b_part
     if sq_ref is not None:
-        sq_ref[...] += jnp.sum(g * g, axis=(1, 2))[:, None]
+        sq_ref[...] += _sum_pn(g * g)
 
 
 def _geff_tile(scal_ref, src_ref, g_ref, s_ref, base_kind, nesterov):
@@ -524,7 +548,7 @@ def _geff_tile(scal_ref, src_ref, g_ref, s_ref, base_kind, nesterov):
     if base_kind == "trace" and nesterov:
         decay = scal_ref[3]
         src = decay * src + g_ref[...].astype(jnp.float32)
-    return src, s_ref[...][:, :, None]  # (1, p, tn), (1, 1, 1)
+    return src, s_ref[...]  # (1, p, tn), (1, 1, 1)
 
 
 def _t2_pogo_kernel(scal_ref, *refs, base_kind, nesterov):
@@ -598,9 +622,7 @@ def _tiled_call(kernel, grid, in_specs, out_specs, out_shape, scal, operands,
             out_specs=out_specs,
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, *operands)
 
@@ -631,7 +653,7 @@ def fused_step_tiled(
     grid = (bsz, nt)
     mat_spec = pl.BlockSpec((1, p, tile_n), lambda i, t, s: (i, 0, t))
     acc_spec = pl.BlockSpec((1, p, p), lambda i, t, s: (i, 0, 0))
-    col_spec = pl.BlockSpec((1, 1), lambda i, t, s: (i, 0))
+    col_spec = _col_spec(1, lambda i, t, s: (i, 0, 0))
 
     # ---- phase 1: moments + (p, p) accumulators
     in_specs = [mat_spec, mat_spec]
@@ -646,7 +668,7 @@ def fused_step_tiled(
         out_shape.append(jax.ShapeDtypeStruct(mu.shape, mu.dtype))
     if base_kind == "vadam":
         out_specs.append(col_spec)
-        out_shape.append(jax.ShapeDtypeStruct((bsz, 1), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, 1, 1), jnp.float32))
     outs = _tiled_call(
         functools.partial(_t1_kernel, base_kind=base_kind, nesterov=nesterov),
         grid, in_specs, out_specs, out_shape, scal, operands, interpret,
@@ -655,7 +677,7 @@ def fused_step_tiled(
     a = outs.pop(0)
     bp = outs.pop(0)
     mu2 = outs.pop(0) if base_kind != "none" else None
-    sq = outs.pop(0) if base_kind == "vadam" else None
+    sq = _to2(outs.pop(0)) if base_kind == "vadam" else None
 
     # ---- inter-phase scalars: O(B) jnp work, no (p, n) traffic
     ps = scal[2]
@@ -676,7 +698,7 @@ def fused_step_tiled(
         in_specs.append(mat_spec)
         operands.append(g)
     in_specs += [acc_spec, acc_spec, col_spec]
-    operands += [a, bp, s_col]
+    operands += [a, bp, _to3(s_col)]
 
     if method == "pogo":
         m, c = _tiled_call(

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pogo_update import _CompilerParams, _phase1_kernel
+from .pogo_update import _phase1_kernel, compiler_params, dot
 
 Array = jax.Array
 
@@ -29,12 +29,12 @@ def _landing_kernel(scal_ref, x_ref, g_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
     dn = (((2,), (2,)), ((0,), (0,)))
     dp = (((2,), (1,)), ((0,), (0,)))
-    a = jax.lax.dot_general(x, x, dn, preferred_element_type=jnp.float32)
-    b = jax.lax.dot_general(x, g, dn, preferred_element_type=jnp.float32)
-    ag = jax.lax.dot_general(a, g, dp, preferred_element_type=jnp.float32)
-    bx = jax.lax.dot_general(b, x, dp, preferred_element_type=jnp.float32)
+    a = dot(x, x, dn)
+    b = dot(x, g, dn)
+    ag = dot(a, g, dp)
+    bx = dot(b, x, dp)
     r = 0.5 * (ag - bx)
-    ax = jax.lax.dot_general(a, x, dp, preferred_element_type=jnp.float32)
+    ax = dot(a, x, dp)
     normal = ax - x  # (A - I) X
     o_ref[...] = (r + lam * normal).astype(o_ref.dtype)
 
@@ -58,6 +58,7 @@ def landing_field(
             out_specs=pl.BlockSpec((block_b, p, n), lambda i, s: (i, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(scal, x, g)
 
@@ -69,10 +70,8 @@ def _field_tile_kernel(scal_ref, x_ref, g_ref, a_ref, b_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
     dp = (((2,), (1,)), ((0,), (0,)))
     a = a_ref[...]
-    r = 0.5 * (jax.lax.dot_general(a, g, dp, preferred_element_type=jnp.float32)
-               - jax.lax.dot_general(b_ref[...], x, dp,
-                                     preferred_element_type=jnp.float32))
-    normal = jax.lax.dot_general(a, x, dp, preferred_element_type=jnp.float32) - x
+    r = 0.5 * (dot(a, g, dp) - dot(b_ref[...], x, dp))
+    normal = dot(a, x, dp) - x
     o_ref[...] = (r + lam * normal).astype(o_ref.dtype)
 
 
@@ -98,9 +97,7 @@ def landing_field_tiled(
             out_specs=[acc_spec, acc_spec],
         ),
         out_shape=[jax.ShapeDtypeStruct((bsz, p, p), jnp.float32)] * 2,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, x, g)
     return pl.pallas_call(
@@ -112,8 +109,6 @@ def landing_field_tiled(
             out_specs=mat_spec,
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, x, g, a, b)

@@ -15,19 +15,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .pogo_update import compiler_params, dot
+
 Array = jax.Array
 
 
 def _ns_kernel(x_ref, o_ref, *, iters: int):
     x = x_ref[...].astype(jnp.float32)  # (bm, p, n)
-    fro = jnp.sqrt(jnp.sum(x * x, axis=(-2, -1), keepdims=True))
+    # Lanes first, then sublanes: one reduction over both axes aborts the
+    # TPU compiler (a layout CHECK) for a batch block larger than one.
+    fro = jnp.sqrt(
+        jnp.sum(jnp.sum(x * x, axis=-1, keepdims=True), axis=-2, keepdims=True)
+    )
     y = x / jnp.maximum(fro, 1e-30)
     dn = (((2,), (2,)), ((0,), (0,)))
     dp = (((2,), (1,)), ((0,), (0,)))
 
     def body(_, y):
-        yy = jax.lax.dot_general(y, y, dn, preferred_element_type=jnp.float32)
-        yyy = jax.lax.dot_general(yy, y, dp, preferred_element_type=jnp.float32)
+        yy = dot(y, y, dn)
+        yyy = dot(yy, y, dp)
         return 1.5 * y - 0.5 * yyy
 
     y = jax.lax.fori_loop(0, iters, body, y)
@@ -46,5 +52,6 @@ def newton_schulz(
         in_specs=[pl.BlockSpec((block_b, p, n), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((block_b, p, n), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(x)

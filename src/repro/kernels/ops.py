@@ -23,6 +23,7 @@ Responsibilities:
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -36,37 +37,44 @@ from . import newton_schulz as _ns
 from . import pogo_update as _pu
 from . import ref
 
-# Conservative VMEM plan: ~16 MiB/core on v5e, keep the working set under
-# ~12 MiB to leave room for semaphores/double-buffering.
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+log = logging.getLogger("repro.kernels")
+
+# Planner VMEM budget per kernel instance. Every kernel compiles with an
+# explicit scoped-VMEM limit of ``pogo_update.VMEM_LIMIT_BYTES`` (64 MiB of
+# the 128 MiB a v5e core has); the budget keeps 16 MiB of that for the
+# compiler's own scratch.
+VMEM_BUDGET_BYTES = 48 * 1024 * 1024
 
 # HBM passes over the (B, p, n) operands per fused step with a momentum
 # base: read X, g, mu; write X', mu' (DESIGN.md §2 cost table). Single
 # source of truth for the ragged-scheduler cost model and the benches.
 FUSED_TRACE_HBM_PASSES = 5
 
-# Per-matrix simultaneously-live fp32 intermediates of each whole-matrix
-# kernel, counted from the actual kernel dataflow — conservatively
-# assuming Mosaic reuses no buffers. (The old ``_WHOLE_ARRAYS = 4``
-# undercounted the POGO kernel, whose live set is x, g, ag, bx, m, cm,
-# out plus the (p, p) a, b, c — large (p, n) shapes could pick a block_b
-# whose true working set blew the budget.) Keys: ``<method>`` for the
-# single-purpose kernels, ``fused_<method>`` for the fused group step
-# (adds the telemetry (p, p) chain), ``+<base>`` suffix adds the
-# in-kernel base-stage buffers.
+# Per-matrix VMEM of each whole-matrix kernel, in fp32 buffers. The TPU
+# compiler's scoped-VMEM check counts every input and output block twice
+# (the pipeline double-buffers them), so blocks count twice here too. The
+# live intermediates are counted from the kernel dataflow, assuming no
+# buffer reuse: more than the compiler keeps, so a plan that fits here
+# compiles. Keys: ``<method>`` for the single-purpose kernels,
+# ``fused_<method>`` for the fused group step, ``tp_*`` for the TP stages
+# (n there is n_local = n / width); the ``+<base>`` suffix adds the
+# moment buffers where the kernel carries them. The (B, 1, 1) per-matrix
+# columns take under 1 KiB a matrix and are not counted.
 _WHOLE_COUNTS = {
-    # method: (count of (p, n) fp32 buffers, count of (p, p) fp32 buffers)
-    "pogo": (7, 3),        # x g ag bx m cm out | a b c
-    "landing": (8, 2),     # x g ag bx r ax normal out | a b
-    "ns": (4, 1),          # x y yyy out | yy
-    "fused_pogo": (8, 6),  # + geff | + cc ccc w
-    "fused_landing": (9, 3),
-    # TP stages run on the LOCAL columns (n here is n_local = n / width)
-    "tp_gram": (3, 3),         # x g gb | a b s
-    "tp_apply_pogo": (6, 10),  # x gb geff r m out | a b s bt c cc ccc w +2 tmp
-    "tp_apply_landing": (6, 12),
+    # method: ((p, n) blocks in + out, (p, p) blocks in + out,
+    #          live (p, n) values, live (p, p) values)
+    "pogo": (3, 0, 7, 3),        # x g | out;  x g ag bx m cm out | a b c
+    "landing": (3, 0, 8, 2),     # x g ag bx r ax normal out | a b
+    "ns": (2, 0, 4, 1),          # x | out;  x y yyy out | yy
+    "fused_pogo": (3, 0, 8, 6),  # + geff | + cc ccc w
+    "fused_landing": (3, 0, 9, 3),
+    "tp_gram": (3, 3, 3, 3),     # x g | gb, a b s;  x g gb | a b s
+    # x gb | out, a b s;  x gb geff r m out | a b s bt c cc ccc w +2 tmp
+    "tp_apply_pogo": (3, 3, 6, 10),
+    "tp_apply_landing": (3, 3, 6, 12),
 }
-_BASE_EXTRA_PN = {"none": 0, "trace": 3, "vadam": 3}  # mu_in, mu', comb/scale
+# Moment buffers: blocks mu in and mu' out; live mu, mu', combination.
+_BASE_EXTRA = {"none": (0, 0), "trace": (2, 3), "vadam": (2, 3)}
 
 
 def _split_stages(stages: str) -> tuple[str, str]:
@@ -75,19 +83,29 @@ def _split_stages(stages: str) -> tuple[str, str]:
 
 
 def whole_vmem_bytes(p_pad: int, n_pad: int, stages: str = "pogo") -> int:
-    """Per-matrix VMEM working set of a whole-matrix kernel variant."""
+    """Per-matrix VMEM of a whole-matrix kernel variant: double-buffered
+    blocks plus live intermediates."""
     method, base = _split_stages(stages)
-    pn, pp = _WHOLE_COUNTS[method]
-    pn += _BASE_EXTRA_PN[base]
+    io_pn, io_pp, pn, pp = _WHOLE_COUNTS[method]
+    if not method.startswith("tp_apply"):  # the finish reads no moments
+        extra_io, extra_live = _BASE_EXTRA[base]
+        io_pn += extra_io
+        pn += extra_live
+    pn += 2 * io_pn
+    pp += 2 * io_pp
     return (pn * p_pad * n_pad + pp * p_pad * p_pad) * 4
 
 
 def tiled_vmem_bytes(p_pad: int, tile_n: int, stages: str = "pogo") -> int:
-    """Per-matrix VMEM working set of the worst tiled phase (phase 2:
-    x, src[, g] and the m/out tile + a, bp, c/w accumulators)."""
+    """Per-matrix VMEM of the worst tiled phase. Blocks, double-buffered:
+    phase 1 reads x, g[, mu] and writes mu' per tile; phase 2 reads x,
+    src[, g] and writes m or x' per tile; the (p, p) a, bp and c or w
+    ride along. Live: about five (p, tile_n) values and two partial
+    grams."""
     _, base = _split_stages(stages)
-    pn = 4 + (2 if base != "none" else 0)
-    pp = 3
+    io_pn = 3 + (1 if base != "none" else 0)
+    pn = 2 * io_pn + 5
+    pp = 2 * 3 + 2
     return (pn * p_pad * tile_n + pp * p_pad * p_pad) * 4
 
 
@@ -496,6 +514,14 @@ def _tp_scal(base_kind, hyper, post_scale, eta=None, lam=None):
     )
 
 
+def _log_tp_fallback(stage, p, n, bsz):
+    log.warning(
+        "%s: no whole-block plan fits VMEM at (B=%d, p=%d, n_local=%d); "
+        "this TP stage runs the jnp reference, not a Pallas kernel",
+        stage, bsz, p, n,
+    )
+
+
 def fused_group_step_tp_partial(
     x, g, *,
     base_kind: str = "none",
@@ -517,7 +543,8 @@ def fused_group_step_tp_partial(
     off-TPU reference route — so the autotune cache keys on the LOCAL
     ``n`` this shard actually sees (the TP analog of the per-shard local
     batch keying, DESIGN.md §Tensor-parallel execution). Only whole-block
-    TP kernels exist; non-whole plans fall back to the jnp reference.
+    TP kernels exist; a non-whole plan runs the jnp reference and says
+    so in the log.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -527,6 +554,8 @@ def fused_group_step_tp_partial(
     kind, arg, p_pad, n_pad = _plan(
         p, n, bsz, x.dtype, f"tp_gram+{base_kind}", interpret
     )
+    if use_pallas and kind != "whole":
+        _log_tp_fallback("tp_gram", p, n, bsz)
     if not use_pallas or kind != "whole":
         return ref.tp_partial_ref(
             x, g, base_kind=base_kind, hyper=hyper, post_scale=post_scale,
@@ -583,6 +612,8 @@ def fused_group_step_tp_finish(
     kind, arg, p_pad, n_pad = _plan(
         p, n, bsz, x.dtype, f"tp_apply_{method}+{base_kind}", interpret
     )
+    if use_pallas and kind != "whole":
+        _log_tp_fallback(f"tp_apply_{method}", p, n, bsz)
     if not use_pallas or kind != "whole":
         return ref.tp_finish_ref(
             x, gbase, payload, eta, method=method, lam=lam,

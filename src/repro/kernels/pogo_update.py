@@ -31,8 +31,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed across pallas releases (TPUCompilerParams -> CompilerParams).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# Scoped-VMEM limit every planned kernel compiles with. The compiler's
+# default on v5e is 16 MiB, against 128 MiB of VMEM per core; the
+# planner's budget (``ops.VMEM_BUDGET_BYTES``) stays below this limit.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def compiler_params(*semantics):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics or None,
+        vmem_limit_bytes=VMEM_LIMIT_BYTES,
+    )
+
+
+def dot(a, b, dims):
+    """fp32 ``dot_general`` at full precision. Mosaic's default runs f32
+    operands through one bf16 pass: on a v5e that put the fused step's X'
+    3e-4 off the fp32 result and its feasibility at 8e-3, not 1e-6."""
+    return jax.lax.dot_general(
+        a, b, dims, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 Array = jax.Array
 
@@ -51,13 +70,13 @@ def _pogo_whole_kernel(scal_ref, x_ref, g_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
     dn = (((2,), (2,)), ((0,), (0,)))  # contract over n, batch over bm
     dp = (((2,), (1,)), ((0,), (0,)))  # (bm,p,p) x (bm,p,n)
-    a = jax.lax.dot_general(x, x, dn, preferred_element_type=jnp.float32)
-    b = jax.lax.dot_general(x, g, dn, preferred_element_type=jnp.float32)
-    ag = jax.lax.dot_general(a, g, dp, preferred_element_type=jnp.float32)
-    bx = jax.lax.dot_general(b, x, dp, preferred_element_type=jnp.float32)
+    a = dot(x, x, dn)
+    b = dot(x, g, dn)
+    ag = dot(a, g, dp)
+    bx = dot(b, x, dp)
     m = x - eta * 0.5 * (ag - bx)
-    c = jax.lax.dot_general(m, m, dn, preferred_element_type=jnp.float32)
-    cm = jax.lax.dot_general(c, m, dp, preferred_element_type=jnp.float32)
+    c = dot(m, m, dn)
+    cm = dot(c, m, dp)
     o_ref[...] = ((1.0 + lam) * m - lam * cm).astype(o_ref.dtype)
 
 
@@ -81,6 +100,7 @@ def pogo_update_whole(
             out_specs=pl.BlockSpec((block_b, p, n), lambda i, s: (i, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(scal, x, g)
 
@@ -95,8 +115,8 @@ def _phase1_kernel(scal_ref, x_ref, g_ref, a_ref, b_ref):
     x = x_ref[...].astype(jnp.float32)  # (1, p, tn)
     g = g_ref[...].astype(jnp.float32)
     dn = (((2,), (2,)), ((0,), (0,)))
-    a_part = jax.lax.dot_general(x, x, dn, preferred_element_type=jnp.float32)
-    b_part = jax.lax.dot_general(x, g, dn, preferred_element_type=jnp.float32)
+    a_part = dot(x, x, dn)
+    b_part = dot(x, g, dn)
 
     @pl.when(t == 0)
     def _init():
@@ -116,12 +136,12 @@ def _phase2_kernel(scal_ref, x_ref, g_ref, a_ref, b_ref, m_ref, c_ref):
     a = a_ref[...]
     b = b_ref[...]
     dp = (((2,), (1,)), ((0,), (0,)))
-    ag = jax.lax.dot_general(a, g, dp, preferred_element_type=jnp.float32)
-    bx = jax.lax.dot_general(b, x, dp, preferred_element_type=jnp.float32)
+    ag = dot(a, g, dp)
+    bx = dot(b, x, dp)
     m = x - eta * 0.5 * (ag - bx)
     m_ref[...] = m
     dn = (((2,), (2,)), ((0,), (0,)))
-    c_part = jax.lax.dot_general(m, m, dn, preferred_element_type=jnp.float32)
+    c_part = dot(m, m, dn)
 
     @pl.when(t == 0)
     def _init():
@@ -136,7 +156,7 @@ def _phase3_kernel(scal_ref, m_ref, c_ref, o_ref):
     m = m_ref[...]
     c = c_ref[...]
     dp = (((2,), (1,)), ((0,), (0,)))
-    cm = jax.lax.dot_general(c, m, dp, preferred_element_type=jnp.float32)
+    cm = dot(c, m, dp)
     o_ref[...] = ((1.0 + lam) * m - lam * cm).astype(o_ref.dtype)
 
 
@@ -161,9 +181,7 @@ def pogo_update_tiled(
             out_specs=[acc_spec, acc_spec],
         ),
         out_shape=[jax.ShapeDtypeStruct((bsz, p, p), jnp.float32)] * 2,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, x, g)
 
@@ -179,9 +197,7 @@ def pogo_update_tiled(
             jax.ShapeDtypeStruct((bsz, p, n), jnp.float32),
             jax.ShapeDtypeStruct((bsz, p, p), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, x, g, a, b)
 
@@ -194,9 +210,7 @@ def pogo_update_tiled(
             out_specs=mat_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, p, n), x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(scal, m, c)
     return out

@@ -7,14 +7,11 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    jax supports them (``jax.sharding.AxisType`` appeared after 0.4.x;
-    older releases are Auto-only so omitting the kwarg is equivalent)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto: the partitioner, not the
+    program, places what the shardings leave open."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -43,6 +43,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from .. import compile_cache
     from ..configs import get_config
     from ..models import ortho, transformer as tfm
     from ..serve import (
@@ -53,6 +54,7 @@ def main(argv=None):
         fold_constraint_set,
     )
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(args.seed)
     params = tfm.init_params(key, cfg)

@@ -4,38 +4,42 @@
       --steps 50 --checkpoint-dir /tmp/ckpt
 
 On a real pod this process runs per-host under the TPU runtime with
-``jax.distributed.initialize()`` (flag --distributed); on this container it
-drives the same code paths single-process. XLA performance flags for
-latency hiding / async collectives are set before jax import.
+``jax.distributed.initialize()`` (flag --distributed); on one host it
+drives the same code paths single-process. The step is compiled before
+the loop starts, so :func:`run` reports the compile time and hands back
+the compiled program.
 """
 
 import argparse
+import dataclasses
 import logging
 import os
 import sys
+import time
+from typing import Any
+
+log = logging.getLogger("repro.launch")
 
 
 def _set_xla_flags(n_fake_devices: int | None):
-    flags = []
-    # collective/compute overlap (latency-hiding scheduler) — TPU-only
-    # flags abort the CPU backend's flag parser, so gate on the runtime.
-    on_tpu = bool(os.environ.get("TPU_NAME") or os.environ.get("TPU_WORKER_ID"))
-    if on_tpu:
-        flags += [
-            "--xla_tpu_enable_async_collective_fusion=true",
-            "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-            "--xla_tpu_overlap_compute_collective_tc=true",
-            "--xla_tpu_enable_async_all_gather=true",
-        ]
     if n_fake_devices:
-        flags.append(f"--xla_force_host_platform_device_count={n_fake_devices}")
-    if flags:
-        os.environ["XLA_FLAGS"] = " ".join(
-            [os.environ.get("XLA_FLAGS", "")] + flags
-        ).strip()
+        os.environ["XLA_FLAGS"] = " ".join([
+            os.environ.get("XLA_FLAGS", ""),
+            f"--xla_force_host_platform_device_count={n_fake_devices}",
+        ]).strip()
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    params: Any
+    opt_state: Any
+    step: int
+    history: list  # [(step, {metric: float})] at the loop's log points
+    compiled: Any  # the compiled train step (jax.stages.Compiled)
+    compile_seconds: float
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config")
@@ -76,8 +80,12 @@ def main(argv=None):
     ap.add_argument("--mesh", default="none", choices=["none", "test", "test-multipod"])
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def run(argv=None) -> TrainRun:
+    """Build, compile and train as the command line says."""
+    args = parse_args(argv)
     _set_xla_flags(args.fake_devices)
     logging.basicConfig(level=logging.INFO, format="%(levelname)s %(name)s: %(message)s")
 
@@ -86,7 +94,7 @@ def main(argv=None):
     if args.distributed:
         jax.distributed.initialize()
 
-    from .. import core
+    from .. import compile_cache, core
     from ..configs import get_config
     from ..data.pipeline import DataConfig, DataIterator
     from ..distributed import shard_hints, sharding
@@ -95,6 +103,7 @@ def main(argv=None):
     from ..train.train_step import TrainConfig, make_train_step
     from .mesh import make_test_mesh
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = None
     if args.mesh != "none":
@@ -155,7 +164,16 @@ def main(argv=None):
         sharding=token_sharding,
     )
 
-    jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
+    batch0 = next(data)
+    data.step = 0  # step-indexed: the loop starts from the same batch
+    t0 = time.perf_counter()
+    compiled = (
+        jax.jit(step_fn, donate_argnums=(0, 1))
+        .lower(params, opt_state, batch0)
+        .compile()
+    )
+    compile_seconds = time.perf_counter() - t0
+    log.info("train step compiled in %.1f s", compile_seconds)
     loop_cfg = LoopConfig(
         total_steps=args.steps,
         save_every=args.save_every,
@@ -164,10 +182,16 @@ def main(argv=None):
         max_rollbacks=args.max_rollbacks,
     )
     params, opt_state, step, history = train(
-        jit_step, params, opt_state, data, loop_cfg
+        compiled, params, opt_state, data, loop_cfg
     )
-    final = history[-1][1] if history else {}
-    print(f"done: step={step} metrics={final}")
+    return TrainRun(params, opt_state, step, history, compiled,
+                    compile_seconds)
+
+
+def main(argv=None):
+    result = run(argv)
+    final = result.history[-1][1] if result.history else {}
+    print(f"done: step={result.step} metrics={final}")
     return 0
 
 

@@ -134,26 +134,32 @@ def _project_leaf(leaf):
 
 
 def project_init(params: PyTree, cfg) -> PyTree:
-    """Project every constrained leaf onto its Stiefel manifold."""
+    """Project every constrained leaf onto its Stiefel manifold, with fp32
+    matmuls at full precision (a TPU's default single bf16 pass would
+    leave the projection ~1e-2 off the manifold)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     out = []
     for path, leaf in flat:
         if _is_orthogonal_path(_path_str(path), cfg):
-            out.append(_project_leaf(leaf))
+            with jax.default_matmul_precision("highest"):
+                out.append(_project_leaf(leaf))
         else:
             out.append(leaf)
     return jax.tree.unflatten(jax.tree.structure(params), out)
 
 
 def max_manifold_distance(params: PyTree, cfg) -> jax.Array:
-    """Max ||X X^H - I|| over all constrained leaves (feasibility telemetry)."""
+    """Max ||X X^H - I|| over all constrained leaves (feasibility telemetry),
+    with the gram at full fp32 precision: at a TPU's default precision
+    this read 5.3e-3 for leaves that sit 1.2e-6 from the manifold."""
     dists = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
         if _is_orthogonal_path(_path_str(path), cfg):
             x = leaf.astype(jnp.float32)
             if x.shape[-2] > x.shape[-1]:
                 x = jnp.swapaxes(x, -1, -2)
-            dists.append(jnp.max(stiefel.manifold_distance(x)))
+            with jax.default_matmul_precision("highest"):
+                dists.append(jnp.max(stiefel.manifold_distance(x)))
     if not dists:
         return jnp.zeros([], jnp.float32)
     return jnp.max(jnp.stack(dists))

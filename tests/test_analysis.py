@@ -19,11 +19,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import ast_rules, lowering, report, rules
 from repro.analysis.lowering import LoweredEntry
-from repro.distributed.compat import shard_map
 from repro.kernels import autotune
 
 

@@ -51,10 +51,11 @@ def test_chosen_plan_fits_vmem_budget(stages, p, n, bsz, tmp_cache):
 
 
 def test_old_accounting_bug_shape_now_tiles(tmp_cache):
-    """(256, 2048) fp32: the old ``_WHOLE_ARRAYS = 4`` estimate (~9.2 MiB)
-    fit the 12 MiB budget, but the kernel's true live set (x, g, ag, bx,
-    m, cm, out + 3x(p,p)) is ~15.5 MiB — the planner must tile now."""
-    p, n = 256, 2048
+    """(256, 4096) fp32: the old ``_WHOLE_ARRAYS = 4`` estimate (~16.8
+    MiB) fits the 48 MiB budget, but the kernel's double-buffered x, g,
+    out blocks plus its live set (x, g, ag, bx, m, cm, out + 3x(p,p)) are
+    ~52.8 MiB — the planner must tile."""
+    p, n = 256, 4096
     p_pad, n_pad = p, n
     old_estimate = p_pad * n_pad * 4 * 4 + p_pad * p_pad * 4 * 3
     assert old_estimate <= ops.VMEM_BUDGET_BYTES  # the bug's premise
@@ -158,23 +159,28 @@ def test_heuristic_hit_is_retimed_once_enabled(tmp_cache):
     assert plan["source"] == "autotune" and plan["block_b"] == 2
 
 
-def test_failing_candidates_are_skipped(tmp_cache):
-    """Timing is best-effort: an uncompilable candidate must not abort the
-    step trace; if every candidate fails, the heuristic default wins."""
+def test_failing_candidates_are_skipped(tmp_cache, caplog):
+    """An uncompilable candidate is logged with its error and skipped; if
+    every candidate fails, the planner raises with every error."""
 
     def flaky(cand):
         if cand["block_b"] == 8:
             raise RuntimeError("mosaic lowering failed")
         return 0.01
 
-    plan = autotune.choose("k6", _cands(), flaky, enabled=True)
+    with caplog.at_level("WARNING", logger="repro.autotune"):
+        plan = autotune.choose("k6", _cands(), flaky, enabled=True)
     assert plan["block_b"] == 2 and plan["source"] == "autotune"
+    assert "mosaic lowering failed" in caplog.text
+    assert "'block_b': 8" in caplog.text
 
     def always_fails(cand):
         raise RuntimeError("no candidate works")
 
-    plan = autotune.choose("k7", _cands(), always_fails, enabled=True)
-    assert plan["block_b"] == 8 and plan["source"] == "heuristic"
+    with pytest.raises(RuntimeError, match="no candidate compiled") as e:
+        autotune.choose("k7", _cands(), always_fails, enabled=True)
+    assert str(e.value).count("no candidate works") == len(_cands())
+    assert tmp_cache.lookup("k7") is None
 
 
 def test_corrupt_cache_file_is_tolerated(tmp_path):
@@ -290,3 +296,25 @@ def test_stale_lock_is_broken(tmp_path, monkeypatch):
     cache.store("k", {"kind": "whole", "block_b": 1, "tile_n": 0})
     assert not lock.exists()  # stale lock broken for the next store
     assert "k" in json.load(open(path))["plans"]
+
+
+def test_plan_cache_follows_the_compile_cache(monkeypatch, tmp_path):
+    """One rule for both caches: JAX_COMPILATION_CACHE_DIR when set, else
+    the fixed ``.jax_cache/`` at the checkout root; REPRO_AUTOTUNE_CACHE
+    still names the plan file outright."""
+    import pathlib
+
+    from repro import compile_cache
+
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.cache_dir() == str(root / ".jax_cache")
+    assert autotune.default_cache_path() == str(
+        root / ".jax_cache" / "repro_autotune.json")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    assert autotune.default_cache_path() == str(
+        tmp_path / "repro_autotune.json")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "p.json"))
+    assert autotune.default_cache_path() == str(tmp_path / "p.json")

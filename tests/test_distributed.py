@@ -146,7 +146,7 @@ def test_compressed_allreduce_error_feedback():
         """
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        from repro.distributed.compat import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_mesh as _make_mesh
         mesh = _make_mesh((8,), ("data",))
 
@@ -237,8 +237,12 @@ def test_group_shard_map_replaces_cpu_miscompile():
         wrapped = shard_hints.shard_group_step(local, 8, 3, pin_inputs=True)
         assert wrapped is not None
         out = jax.jit(lambda a, b: wrapped(jnp.concatenate([a, b], 0)))(a, b)
+        # The reference is the same jitted product, unsharded on one
+        # device: XLA's CPU dot and NumPy's round differently (1.7e-4 at
+        # this shape), and the pin is about partitioning, not rounding.
         x_np = np.concatenate([a, b], 0)
-        assert np.array_equal(np.asarray(out), x_np @ np.swapaxes(x_np, -1, -2)), \\
+        ref = jax.jit(local)(jax.device_put(x_np, jax.devices()[0]))
+        assert np.array_equal(np.asarray(out), np.asarray(ref)), \\
             "shard_map group path returned wrong values on the concat repro"
         shard_hints.set_mesh(None)
 

@@ -395,3 +395,25 @@ def test_resolve_fused_base_contract():
         np.asarray(state2[0].momentum["a"]),
         np.asarray(state[0].momentum["a"] + 1.0),
     )
+
+
+def test_tp_stage_without_whole_plan_says_it_fell_back(monkeypatch, caplog):
+    """Only whole-block TP kernels exist: a TP stage whose local columns
+    fit no whole plan runs the jnp reference, and says so in the log."""
+    monkeypatch.setattr(ops, "VMEM_BUDGET_BYTES", 64 * 1024)
+    x, g, mu, nu = _operands((3, 16, 512), with_mu=True, with_nu=True)
+    hyper = (0.9, 0.999, 1e-8)
+    with caplog.at_level("WARNING", logger="repro.kernels"):
+        got = ops.fused_group_step_tp(
+            x, g, 0.1, method="pogo", lam=0.5, base_kind="vadam",
+            hyper=hyper, mu=mu, nu=nu, count=jnp.asarray(2), tp_shards=2,
+            interpret=True, use_pallas=True,
+        )
+    assert "tp_gram: no whole-block plan" in caplog.text
+    assert "tp_apply_pogo: no whole-block plan" in caplog.text
+    want = ops.fused_group_step_tp(
+        x, g, 0.1, method="pogo", lam=0.5, base_kind="vadam", hyper=hyper,
+        mu=mu, nu=nu, count=jnp.asarray(2), tp_shards=2, use_pallas=False,
+    )
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -379,7 +379,11 @@ def test_greedy_golden_is_stable(smollm_f32):
     assert out == GOLDEN_SMOLLM_SEED0
 
 
-GOLDEN_SMOLLM_SEED0 = [354, 439, 297, 415, 415, 415]
+# Pinned under jax 0.9, where ``jax_threefry_partitionable`` defaults to
+# True. That default changed the bits ``jax.random`` draws for the seed-0
+# init; with the flag set back to False the same engine still emits the
+# tokens pinned under jax 0.4 ([354, 439, 297, 415, 415, 415]).
+GOLDEN_SMOLLM_SEED0 = [66, 477, 510, 221, 451, 331]
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-2b", "falcon-mamba-7b"])

@@ -131,3 +131,23 @@ def test_resume_bit_identical(tmp_path, grouping):
         for da, db in zip(sa.last_distance.per_group,
                           sb.last_distance.per_group):
             np.testing.assert_array_equal(np.asarray(da), np.asarray(db))
+
+
+def test_launcher_run_resumes_with_the_compiled_step(monkeypatch, tmp_path):
+    """``launch.train.run`` compiles the step before the loop, trains with
+    the compiled program, and resumes from its own checkpoint with it.
+    JAX_COMPILATION_CACHE_DIR set after import is read by no one, which
+    keeps this process's compiles out of the checkout's cache."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    from repro.launch import train as launch
+
+    argv = ["--arch", "smollm-360m", "--smoke", "--global-batch", "2",
+            "--seq-len", "16", "--pogo-kernel", "--save-every", "2",
+            "--checkpoint-dir", str(tmp_path / "ckpt")]
+    first = launch.run(argv + ["--steps", "2"])
+    assert first.step == 2 and first.compile_seconds > 0
+    assert "HloModule" in first.compiled.as_text()
+    again = launch.run(argv + ["--steps", "3"])
+    assert again.step == 3
+    final = again.history[-1][1]
+    assert np.isfinite(final["loss"]) and final["ortho_distance"] < 1e-4
