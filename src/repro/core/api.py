@@ -1369,7 +1369,8 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                 x_next = jax.lax.cond(
                     do, lambda v: stiefel.project_newton_schulz(v), lambda v: v, x_next
                 )
-            ug = (x_next - x32).astype(xg.dtype)
+            with jax.named_scope("unstack"):
+                ug = (x_next - x32).astype(xg.dtype)
             # Telemetry rides the batch: one (B,) distance array per group
             # instead of thousands of per-leaf scalars.
             y = (xg + ug).astype(jnp.promote_types(xg.dtype, jnp.float32))
@@ -1417,7 +1418,8 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                 x_next, dist = jax.lax.cond(
                     do, _proj, lambda args: args, (x_next, dist)
                 )
-            ug = (x_next - x32).astype(xg.dtype)
+            with jax.named_scope("unstack"):
+                ug = (x_next - x32).astype(xg.dtype)
             # The telemetry contract measures the *stored* iterate. For
             # reduced-precision params the f32 kernel distance would
             # under-report the post-cast infeasibility (bf16 rounding
@@ -1476,7 +1478,8 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                 post_scale=fused_base.post_scale,
                 nu=nug, count=bcount, pv=pv,
             )
-            ug = (x2 - x32).astype(xg.dtype)
+            with jax.named_scope("unstack"):
+                ug = (x2 - x32).astype(xg.dtype)
             return ug, dist.astype(jnp.float32), mu2, nu2, ef2
 
         def _repair(xg, ug, dist, pv, thresh):
@@ -1601,8 +1604,14 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                 esc = prev_max > jnp.where(
                     esc_prev, wd.soft * wd.release, wd.soft
                 ).astype(jnp.float32)
-            xg = _gather_group(group, leaves)
-            gg = _gather_group(group, gleaves)
+            with jax.named_scope("stack"):
+                xg = _gather_group(group, leaves)
+                gg = _gather_group(group, gleaves)
+                mug = nug = None
+                if fused_now and mu_leaves is not None:
+                    mug = _gather_group(group, mu_leaves)
+                if fused_now and nu_leaves is not None:
+                    nug = _gather_group_scalars(group, nu_leaves)
             # Ragged megagroups carry their per-matrix true shapes as
             # (B,) operands: batch-leading, so the shard_map schedule
             # partitions them with the stack and each shard masks exactly
@@ -1611,107 +1620,98 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
             pv = nv = None
             if pvnv is not None:
                 pv, nv = jnp.asarray(pvnv[0]), jnp.asarray(pvnv[1])
-            if fused_now:
-                mug = (
-                    _gather_group(group, mu_leaves)
-                    if mu_leaves is not None else None
-                )
-                nug = (
-                    _gather_group_scalars(group, nu_leaves)
-                    if nu_leaves is not None else None
-                )
-                if wd is not None:
-                    ug, dist, mu2, nu2, rep_b = _run_group_step(
-                        functools.partial(group_step_fused_watchdog, group),
-                        group,
-                        (xg, gg, mug, nug, eta32, count, base_count, pv, nv,
-                         esc),
-                        (3, 1, None if mug is None else 3,
-                         None if nug is None else 1, 1),
-                    )
-                else:
-                    spec = tp_specs[gi]
-                    res = None
-                    if spec is not None:
-                        # Zero-pad n to shard granularity (exactly inert
-                        # through the TP algebra — TpSpec docstring) and
-                        # crop after; the EF carry re-arms from zeros on
-                        # any shape mismatch (fresh run, TP width change).
-                        efg = None
-                        if cfg.tp_compress:
-                            from ..kernels import ref as kref
-
-                            kw = kref.tp_payload_width(
-                                group.p, fused_base.kind
-                            )
-                            ef_shape = (spec.width, group.batch, kw)
-                            if (ef_prev is not None
-                                    and len(ef_prev.residuals)
-                                    == len(plan.groups)
-                                    and getattr(
-                                        ef_prev.residuals[gi], "shape", None
-                                    ) == ef_shape):
-                                efg = ef_prev.residuals[gi]
-                            else:
-                                efg = jnp.zeros(ef_shape, jnp.float32)
-                        mug_p = (
-                            _pad_cols(mug, spec.n_pad)
-                            if mug is not None else None
-                        )
-                        res = _run_group_step_tp(
-                            functools.partial(group_step_fused_tp, group),
-                            group, spec.n_pad,
-                            (_pad_cols(xg, spec.n_pad),
-                             _pad_cols(gg, spec.n_pad),
-                             mug_p, nug, eta32, count, base_count, pv, nv,
-                             efg),
-                            ("xn", "b",
-                             None if mug is None else "xn",
-                             None if nug is None else "b",
-                             None if efg is None else "ef"),
-                        )
-                    if res is not None:
-                        ug, dist, mu2, nu2, new_ef[gi] = res
-                        if spec.padded:
-                            ug = ug[..., :group.n]
-                            if mu2 is not None:
-                                mu2 = mu2[..., :group.n]
-                    else:
-                        # No TP spec, or the mesh vanished between trace
-                        # decisions — the DP-or-unsharded fused dispatch.
-                        ug, dist, mu2, nu2 = _run_group_step(
-                            functools.partial(group_step_fused, group),
+            mu2 = nu2 = None
+            with jax.named_scope("step"):
+                if fused_now:
+                    if wd is not None:
+                        ug, dist, mu2, nu2, rep_b = _run_group_step(
+                            functools.partial(group_step_fused_watchdog, group),
                             group,
-                            (xg, gg, mug, nug, eta32, count, base_count,
-                             pv, nv),
+                            (xg, gg, mug, nug, eta32, count, base_count, pv, nv,
+                             esc),
                             (3, 1, None if mug is None else 3,
-                             None if nug is None else 1),
+                             None if nug is None else 1, 1),
                         )
-                if mu2 is not None:
-                    _scatter_group(group, mu2, mu_out)
-                if nu2 is not None:
-                    _scatter_group_scalars(group, nu2, nu_out)
-            else:
-                keys = None
-                if all_keys is not None:
-                    kparts = [
-                        all_keys[m.key_base:m.key_base + m.count]
-                        for m in group.members
-                    ]
-                    keys = (
-                        kparts[0] if len(kparts) == 1
-                        else jnp.concatenate(kparts)
-                    )
-                if wd is not None:
-                    ug, dist, rep_b = _run_group_step(
-                        functools.partial(group_step_watchdog, group), group,
-                        (xg, gg, keys, eta32, count, pv, nv, esc), (3, 1, 1),
-                    )
+                    else:
+                        spec = tp_specs[gi]
+                        res = None
+                        if spec is not None:
+                            # Zero-pad n to shard granularity (exactly inert
+                            # through the TP algebra — TpSpec docstring) and
+                            # crop after; the EF carry re-arms from zeros on
+                            # any shape mismatch (fresh run, TP width change).
+                            efg = None
+                            if cfg.tp_compress:
+                                from ..kernels import ref as kref
+
+                                kw = kref.tp_payload_width(
+                                    group.p, fused_base.kind
+                                )
+                                ef_shape = (spec.width, group.batch, kw)
+                                if (ef_prev is not None
+                                        and len(ef_prev.residuals)
+                                        == len(plan.groups)
+                                        and getattr(
+                                            ef_prev.residuals[gi], "shape", None
+                                        ) == ef_shape):
+                                    efg = ef_prev.residuals[gi]
+                                else:
+                                    efg = jnp.zeros(ef_shape, jnp.float32)
+                            mug_p = (
+                                _pad_cols(mug, spec.n_pad)
+                                if mug is not None else None
+                            )
+                            res = _run_group_step_tp(
+                                functools.partial(group_step_fused_tp, group),
+                                group, spec.n_pad,
+                                (_pad_cols(xg, spec.n_pad),
+                                 _pad_cols(gg, spec.n_pad),
+                                 mug_p, nug, eta32, count, base_count, pv, nv,
+                                 efg),
+                                ("xn", "b",
+                                 None if mug is None else "xn",
+                                 None if nug is None else "b",
+                                 None if efg is None else "ef"),
+                            )
+                        if res is not None:
+                            ug, dist, mu2, nu2, new_ef[gi] = res
+                            if spec.padded:
+                                with jax.named_scope("unstack"):
+                                    ug = ug[..., :group.n]
+                                    if mu2 is not None:
+                                        mu2 = mu2[..., :group.n]
+                        else:
+                            # No TP spec, or the mesh vanished between trace
+                            # decisions — the DP-or-unsharded fused dispatch.
+                            ug, dist, mu2, nu2 = _run_group_step(
+                                functools.partial(group_step_fused, group),
+                                group,
+                                (xg, gg, mug, nug, eta32, count, base_count,
+                                 pv, nv),
+                                (3, 1, None if mug is None else 3,
+                                 None if nug is None else 1),
+                            )
                 else:
-                    ug, dist = _run_group_step(
-                        functools.partial(group_step, method, group), group,
-                        (xg, gg, keys, eta32, count, pv, nv), (3, 1),
-                    )
+                    keys = None
+                    if all_keys is not None:
+                        kparts = [
+                            all_keys[m.key_base:m.key_base + m.count]
+                            for m in group.members
+                        ]
+                        keys = (
+                            kparts[0] if len(kparts) == 1
+                            else jnp.concatenate(kparts)
+                        )
+                    if wd is not None:
+                        ug, dist, rep_b = _run_group_step(
+                            functools.partial(group_step_watchdog, group), group,
+                            (xg, gg, keys, eta32, count, pv, nv, esc), (3, 1, 1),
+                        )
+                    else:
+                        ug, dist = _run_group_step(
+                            functools.partial(group_step, method, group), group,
+                            (xg, gg, keys, eta32, count, pv, nv), (3, 1),
+                        )
             if wd is not None:
                 new_esc.append(esc)
                 new_repairs.append(
@@ -1722,7 +1722,12 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                     + (esc & ~wstate.escalated[gi]).astype(jnp.int32)
                 )
             dists.append(dist)
-            _scatter_group(group, ug, out)
+            with jax.named_scope("unstack"):
+                if mu2 is not None:
+                    _scatter_group(group, mu2, mu_out)
+                if nu2 is not None:
+                    _scatter_group_scalars(group, nu2, nu_out)
+                _scatter_group(group, ug, out)
         if fused_now:
             mu_tree2 = (
                 jax.tree.unflatten(treedef, mu_out)
@@ -1751,7 +1756,15 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
             extras=extras,
         )
 
-    return GradientTransformation(_full_precision(init), _full_precision(update))
+    # The driver's device work carries the named scopes ``ortho`` and, per
+    # group, ``stack`` (gather and pad), ``step`` (the group step and its
+    # kernel) and ``unstack`` (crop, the update as a difference, scatter).
+    def scoped_update(grads, state, params=None):
+        with jax.named_scope("ortho"):
+            return update(grads, state, params)
+
+    return GradientTransformation(_full_precision(init),
+                                  _full_precision(scoped_update))
 
 
 def _full_precision(fn):

@@ -98,6 +98,7 @@ def flash_attention_fwd(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),

@@ -245,6 +245,7 @@ def fused_step_whole(
     )
     outs = pl.pallas_call(
         kernel,
+        name=f"{method}_fused_whole",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz // block_b,),
@@ -363,6 +364,7 @@ def tp_gram_whole(
         functools.partial(
             _tp_gram_kernel, base_kind=base_kind, nesterov=nesterov
         ),
+        name="fused_tp_gram",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz // block_b,),
@@ -481,6 +483,7 @@ def tp_apply_whole(
             _tp_apply_kernel, method=method, base_kind=base_kind,
             p_valid=p if p_valid is None else p_valid, ragged=pv is not None,
         ),
+        name=f"{method}_fused_tp_apply",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz // block_b,),
@@ -611,10 +614,11 @@ def _t2_landing_kernel(scal_ref, *refs, base_kind, nesterov):
     w_ref[...] += w_part
 
 
-def _tiled_call(kernel, grid, in_specs, out_specs, out_shape, scal, operands,
-                interpret):
+def _tiled_call(kernel, name, grid, in_specs, out_specs, out_shape, scal,
+                operands, interpret):
     return pl.pallas_call(
         kernel,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -671,7 +675,8 @@ def fused_step_tiled(
         out_shape.append(jax.ShapeDtypeStruct((bsz, 1, 1), jnp.float32))
     outs = _tiled_call(
         functools.partial(_t1_kernel, base_kind=base_kind, nesterov=nesterov),
-        grid, in_specs, out_specs, out_shape, scal, operands, interpret,
+        f"{method}_fused_tiled_moments", grid, in_specs, out_specs, out_shape,
+        scal, operands, interpret,
     )
     outs = list(outs)
     a = outs.pop(0)
@@ -705,7 +710,7 @@ def fused_step_tiled(
             functools.partial(
                 _t2_pogo_kernel, base_kind=base_kind, nesterov=nesterov
             ),
-            grid, in_specs, [mat_spec, acc_spec],
+            "pogo_fused_tiled_leap", grid, in_specs, [mat_spec, acc_spec],
             [
                 jax.ShapeDtypeStruct((bsz, p, n), jnp.float32),
                 jax.ShapeDtypeStruct((bsz, p, p), jnp.float32),
@@ -713,7 +718,8 @@ def fused_step_tiled(
             scal, operands, interpret,
         )
         x2 = _tiled_call(
-            _phase3_kernel, grid, [mat_spec, acc_spec], mat_spec,
+            _phase3_kernel, "pogo_fused_tiled_land", grid, [mat_spec, acc_spec],
+            mat_spec,
             jax.ShapeDtypeStruct((bsz, p, n), x.dtype), scal, [m, c], interpret,
         )
         lam = scal[1]
@@ -725,7 +731,7 @@ def fused_step_tiled(
             functools.partial(
                 _t2_landing_kernel, base_kind=base_kind, nesterov=nesterov
             ),
-            grid, in_specs, [mat_spec, acc_spec],
+            "landing_fused_tiled_step", grid, in_specs, [mat_spec, acc_spec],
             [
                 jax.ShapeDtypeStruct((bsz, p, n), x.dtype),
                 jax.ShapeDtypeStruct((bsz, p, p), jnp.float32),

@@ -48,6 +48,7 @@ def landing_field(
     scal = jnp.asarray([lam], jnp.float32)
     return pl.pallas_call(
         _landing_kernel,
+        name="landing_whole",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz // block_b,),
@@ -90,6 +91,7 @@ def landing_field_tiled(
     acc_spec = pl.BlockSpec((1, p, p), lambda i, t, s: (i, 0, 0))
     a, b = pl.pallas_call(
         _phase1_kernel,
+        name="landing_tiled_grams",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, nt),
@@ -102,6 +104,7 @@ def landing_field_tiled(
     )(scal, x, g)
     return pl.pallas_call(
         _field_tile_kernel,
+        name="landing_tiled_field",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, nt),

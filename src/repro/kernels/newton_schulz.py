@@ -48,6 +48,7 @@ def newton_schulz(
     assert bsz % block_b == 0, (bsz, block_b)
     return pl.pallas_call(
         functools.partial(_ns_kernel, iters=iters),
+        name="newton_schulz",
         grid=(bsz // block_b,),
         in_specs=[pl.BlockSpec((block_b, p, n), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((block_b, p, n), lambda i: (i, 0, 0)),

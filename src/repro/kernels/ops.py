@@ -403,16 +403,20 @@ def _fused_dispatch(x, g, mu, nu, pv, eta, lam, count, *, method, base_kind,
     pv2d = pv.reshape(bsz, 1).astype(jnp.int32) if ragged else None
     if kind == "tiled":
         n_pad = _round_up(n_pad, arg)
-    xp = _pad_pn(x, p_pad, n_pad)
-    gp = _pad_pn(g, p_pad, n_pad)
-    mup = _pad_pn(mu, p_pad, n_pad) if mu is not None else None
+    # Padding to the plan's shape and cropping back are named as the
+    # constraint driver's stacking and unstacking (core/api.py).
+    with jax.named_scope("stack"):
+        xp = _pad_pn(x, p_pad, n_pad)
+        gp = _pad_pn(g, p_pad, n_pad)
+        mup = _pad_pn(mu, p_pad, n_pad) if mu is not None else None
+        if kind == "whole":
+            block_b = max(1, min(arg, bsz))
+            b_pad = _round_up(bsz, block_b)
+            xp, gp = _pad_b(xp, b_pad), _pad_b(gp, b_pad)
+            mup = _pad_b(mup, b_pad) if mup is not None else None
+            nup = _pad_b(nu2d, b_pad) if nu2d is not None else None
+            pvp = _pad_b(pv2d, b_pad) if pv2d is not None else None
     if kind == "whole":
-        block_b = max(1, min(arg, bsz))
-        b_pad = _round_up(bsz, block_b)
-        xp, gp = _pad_b(xp, b_pad), _pad_b(gp, b_pad)
-        mup = _pad_b(mup, b_pad) if mup is not None else None
-        nup = _pad_b(nu2d, b_pad) if nu2d is not None else None
-        pvp = _pad_b(pv2d, b_pad) if pv2d is not None else None
         x2, mu2, nu2, dist = _fs.fused_step_whole(
             xp, gp, mup, nup, scal, method=method, base_kind=base_kind,
             nesterov=nesterov, block_b=block_b, interpret=interpret,
@@ -424,10 +428,11 @@ def _fused_dispatch(x, g, mu, nu, pv, eta, lam, count, *, method, base_kind,
             nesterov=nesterov, tile_n=arg, interpret=interpret,
             p_valid=p, pv=pv2d,
         )
-    x2 = x2[:bsz, :p, :n]
-    mu2 = mu2[:bsz, :p, :n] if mu2 is not None else None
-    nu2 = nu2[:bsz, 0].astype(nu.dtype) if nu2 is not None else None
-    dist = dist[:bsz, 0]
+    with jax.named_scope("unstack"):
+        x2 = x2[:bsz, :p, :n]
+        mu2 = mu2[:bsz, :p, :n] if mu2 is not None else None
+        nu2 = nu2[:bsz, 0].astype(nu.dtype) if nu2 is not None else None
+        dist = dist[:bsz, 0]
     return x2, mu2, nu2, dist
 
 

@@ -90,6 +90,7 @@ def pogo_update_whole(
     grid = (bsz // block_b,)
     return pl.pallas_call(
         _pogo_whole_kernel,
+        name="pogo_whole",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -174,6 +175,7 @@ def pogo_update_tiled(
 
     a, b = pl.pallas_call(
         _phase1_kernel,
+        name="pogo_tiled_grams",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, nt),
@@ -187,6 +189,7 @@ def pogo_update_tiled(
 
     m, c = pl.pallas_call(
         _phase2_kernel,
+        name="pogo_tiled_leap",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, nt),
@@ -203,6 +206,7 @@ def pogo_update_tiled(
 
     out = pl.pallas_call(
         _phase3_kernel,
+        name="pogo_tiled_land",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, nt),

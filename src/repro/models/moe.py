@@ -10,6 +10,9 @@ aux load-balancing loss drives routing toward balance).
 
 Expert weights carry logical axes ("experts", "embed", "mlp") — EP shards
 "experts", TP shards "mlp" (see distributed/sharding.py).
+
+Named scopes ``route``, ``dispatch``, ``experts`` and ``combine`` label the
+four phases under the caller's ``moe`` scope.
 """
 
 from __future__ import annotations
@@ -62,16 +65,17 @@ def moe_apply(params, x: Array, cfg, capacity_factor: float | None = None):
         outs, auxs = jax.lax.map(one, xc)
         return outs.swapaxes(0, 1).reshape(b, s, d), jnp.mean(auxs)
 
-    router_logits = layers._mm(x, params["router"].astype(dt)).astype(jnp.float32)
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (B, S, E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (B, S, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    with jax.named_scope("route"):
+        router_logits = layers._mm(x, params["router"].astype(dt)).astype(jnp.float32)
+        probs = jax.nn.softmax(router_logits, axis=-1)  # (B, S, E)
+        gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (B, S, k)
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
 
-    # Load-balancing aux loss (Switch-style): E * sum_e f_e * p_e
-    me = jnp.mean(probs, axis=(0, 1))
-    one_hot_top1 = jax.nn.one_hot(expert_idx[..., 0], e, dtype=jnp.float32)
-    ce = jnp.mean(one_hot_top1, axis=(0, 1))
-    aux_loss = e * jnp.sum(me * ce)
+        # Load-balancing aux loss (Switch-style): E * sum_e f_e * p_e
+        me = jnp.mean(probs, axis=(0, 1))
+        one_hot_top1 = jax.nn.one_hot(expert_idx[..., 0], e, dtype=jnp.float32)
+        ce = jnp.mean(one_hot_top1, axis=(0, 1))
+        aux_loss = e * jnp.sum(me * ce)
 
     cap = int(max(1, (s * k * capacity_factor) // e))
 
@@ -94,20 +98,24 @@ def moe_apply(params, x: Array, cfg, capacity_factor: float | None = None):
         weight = (sorted_gate * keep).astype(dt)
         return dispatched[: e * cap], dest, sorted_tok, weight
 
-    dispatched, dest, tok, weight = jax.vmap(dispatch_row)(x, expert_idx, gate_vals)
-    dispatched = dispatched.reshape(b, e, cap, d)
-    # pin the batch sharding through the vmapped scatter (GSPMD loses it and
-    # replicates the dispatch buffers: observed 208 GiB/dev on granite-moe)
-    dispatched = shard_hints.activation(dispatched)
+    with jax.named_scope("dispatch"):
+        dispatched, dest, tok, weight = jax.vmap(dispatch_row)(
+            x, expert_idx, gate_vals)
+        dispatched = dispatched.reshape(b, e, cap, d)
+        # pin the batch sharding through the vmapped scatter (GSPMD loses it
+        # and replicates the dispatch buffers: observed 208 GiB/dev on
+        # granite-moe)
+        dispatched = shard_hints.activation(dispatched)
 
     # ---- expert FFN (batched over batch x experts): SwiGLU
     # NOTE: no preferred_element_type here — the TPU MXU accumulates bf16
     # dots in fp32 regardless, and the CPU runtime (tests) lacks a
     # BF16xBF16=F32 thunk for this batched-dot pattern.
-    gate = jnp.einsum("becd,edf->becf", dispatched, params["w_gate"].astype(dt))
-    up = jnp.einsum("becd,edf->becf", dispatched, params["w_up"].astype(dt))
-    h = jax.nn.silu(gate) * up
-    expert_out = jnp.einsum("becf,efd->becd", h, params["w_down"].astype(dt))
+    with jax.named_scope("experts"):
+        gate = jnp.einsum("becd,edf->becf", dispatched, params["w_gate"].astype(dt))
+        up = jnp.einsum("becd,edf->becf", dispatched, params["w_up"].astype(dt))
+        h = jax.nn.silu(gate) * up
+        expert_out = jnp.einsum("becf,efd->becd", h, params["w_down"].astype(dt))
 
     # ---- combine: gather back to token slots, weight by gates
     def combine_row(row_out, dest_r, tok_r, w_r):
@@ -116,6 +124,7 @@ def moe_apply(params, x: Array, cfg, capacity_factor: float | None = None):
         gathered = flat[dest_r] * w_r[:, None]
         return jnp.zeros((s, d), dt).at[tok_r].add(gathered)
 
-    combined = jax.vmap(combine_row)(expert_out, dest, tok, weight)
-    combined = shard_hints.activation(combined)
+    with jax.named_scope("combine"):
+        combined = jax.vmap(combine_row)(expert_out, dest, tok, weight)
+        combined = shard_hints.activation(combined)
     return combined, aux_loss

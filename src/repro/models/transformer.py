@@ -16,6 +16,12 @@ Block kinds:
 
 Entry points: ``init_params``, ``forward``, ``loss_fn``, ``prefill``,
 ``decode_step``, ``init_cache``, ``cache_specs``.
+
+Named scopes label the layers in the compiled program's metadata, so a
+profile can put device time down to them: ``embed``, ``blocks`` (the layer
+stack), ``attention``, ``mlp``, ``moe`` (children in ``moe.py``) and
+``head_loss`` (final norm, unembedding, cross-entropy). Backward and
+recomputed ops keep their layer's scope.
 """
 
 from __future__ import annotations
@@ -126,34 +132,40 @@ def _apply_block(
         # archs with cfg.attention_window use SWA on every attention layer
         # (starcoder2/mixtral global SWA; recurrentgemma local_attn blocks)
         window = cfg.attention_window
-        if paged is not None and cache is not None:
-            block_tables, write_mask = paged
-            attn_out, new_cache = attention.paged_attention_apply(
-                p["inner"], h, cfg, cache, positions=positions,
-                block_tables=block_tables, write_mask=write_mask,
-                window=window,
-            )
-        else:
-            attn_out, new_cache = attention.attention_apply(
-                p["inner"], h, cfg, positions=positions, causal=causal,
-                window=window, cache=cache,
-            )
+        with jax.named_scope("attention"):
+            if paged is not None and cache is not None:
+                block_tables, write_mask = paged
+                attn_out, new_cache = attention.paged_attention_apply(
+                    p["inner"], h, cfg, cache, positions=positions,
+                    block_tables=block_tables, write_mask=write_mask,
+                    window=window,
+                )
+            else:
+                attn_out, new_cache = attention.attention_apply(
+                    p["inner"], h, cfg, positions=positions, causal=causal,
+                    window=window, cache=cache,
+                )
         x = x + attn_out
         if memory is not None and "cross" in p:
             hc = layers.rmsnorm(p["cross_norm"], x, cfg.norm_eps)
-            x = x + attention.cross_attention_apply(p["cross"], hc, memory, cfg)
+            with jax.named_scope("attention"):
+                x = x + attention.cross_attention_apply(p["cross"], hc, memory, cfg)
         h2 = layers.rmsnorm(p["norm2"], x, cfg.norm_eps)
         if kind == "moe_attn":
-            ffn_out, aux = moe.moe_apply(p["ffn"], h2, cfg)
+            with jax.named_scope("moe"):
+                ffn_out, aux = moe.moe_apply(p["ffn"], h2, cfg)
         else:
-            ffn_out = layers.mlp_apply(p["ffn"], h2, cfg.mlp_activation)
+            with jax.named_scope("mlp"):
+                ffn_out = layers.mlp_apply(p["ffn"], h2, cfg.mlp_activation)
         x = x + ffn_out
     elif kind == "rglru":
         state, conv_state = cache if cache is not None else (None, None)
         out, new_state = rglru.rglru_apply(p["inner"], h, cfg, state, conv_state)
         x = x + out
         h2 = layers.rmsnorm(p["norm2"], x, cfg.norm_eps)
-        x = x + layers.mlp_apply(p["ffn"], h2, cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            ffn_out = layers.mlp_apply(p["ffn"], h2, cfg.mlp_activation)
+        x = x + ffn_out
         new_cache = new_state
     elif kind == "mamba":
         state, conv_state = cache if cache is not None else (None, None)
@@ -292,7 +304,8 @@ def forward(
     """
     memory = encoder_memory
     n_prefix = 0
-    x = layers.embed(params["embed"], tokens, cfg.dtype)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], tokens, cfg.dtype)
     if cfg.encoder_layers and memory is None:
         memory = _run_encoder(
             params, cfg, encoder_tokens=encoder_tokens, frontend_embeds=frontend_embeds
@@ -301,12 +314,14 @@ def forward(
         x = jnp.concatenate([frontend_embeds.astype(cfg.dtype), x], axis=1)
         n_prefix = frontend_embeds.shape[1]
     x = shard_hints.activation(x)
-    x, aux, new_caches = _run_blocks(
-        params, x, cfg, positions=positions, caches=caches, memory=memory,
-        paged=paged,
-    )
+    with jax.named_scope("blocks"):
+        x, aux, new_caches = _run_blocks(
+            params, x, cfg, positions=positions, caches=caches, memory=memory,
+            paged=paged,
+        )
     x = shard_hints.activation(x)
-    x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("head_loss"):
+        x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x, aux, new_caches, n_prefix
 
 
@@ -323,10 +338,11 @@ def loss_fn(params, cfg, batch, aux_weight: float = 0.01):
     if n_prefix:
         hidden = hidden[:, n_prefix:]
     embed_params = params.get("unembed", params["embed"])
-    ce = layers.chunked_cross_entropy(
-        hidden, embed_params, batch["labels"], cfg.loss_chunk,
-        unroll=cfg.inner_unroll,
-    )
+    with jax.named_scope("head_loss"):
+        ce = layers.chunked_cross_entropy(
+            hidden, embed_params, batch["labels"], cfg.loss_chunk,
+            unroll=cfg.inner_unroll,
+        )
     return ce + aux_weight * aux, {"ce": ce, "aux": aux}
 
 

@@ -58,7 +58,8 @@ def chain(*transforms: GradientTransformation) -> GradientTransformation:
 
 
 def apply_updates(params: PyTree, updates: PyTree) -> PyTree:
-    return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
+    with jax.named_scope("apply_updates"):
+        return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
 
 
 def scale(factor: float) -> GradientTransformation:

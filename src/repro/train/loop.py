@@ -44,6 +44,11 @@ import numpy as np
 from ..checkpoint import checkpoint as ckpt
 
 log = logging.getLogger("repro.train")
+# Host spans on the profiler's clock (about a microsecond each when no
+# profiler is attached): each iteration is a ``train`` step span holding
+# ``train.batch``, ``train.dispatch``, ``train.wait``, ``train.report`` and
+# ``train.checkpoint``, so a device trace can put its idle gaps down to them.
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -178,100 +183,108 @@ def train(
         step = start_step
         try:
             while step < loop_cfg.total_steps:
-                if step in poisoned:
-                    _ = next(data_iter)  # consume and drop the poison batch
-                    log.warning("skipping poisoned batch at step %d", step)
-                    step += 1
-                    continue
-                if fault_plan is not None:
-                    delay = fault_plan.step_delay(step)
-                    if delay:
-                        time.sleep(delay)
-                    scale = fault_plan.drift_scale(step)
-                    if scale is not None:
-                        params = (drift_apply or _default_drift)(params, scale)
-                        log.warning(
-                            "fault: drift_inject scale=%.4f at step %d", scale, step
-                        )
-                    if fault_plan.nan_grad(step):
-                        params = _poison_params(params)
-                        log.warning("fault: nan_grad at step %d", step)
-                t0 = time.monotonic()
-                batch = next(data_iter)
-                params, opt_state, metrics = train_step(params, opt_state, batch)
-                # lint-ok: block-in-loop deliberate per-step sync: the
-                # straggler detector times wall-clock per step
-                jax.block_until_ready(metrics["loss"])
-                dt = time.monotonic() - t0
-                if loop_cfg.rollback and _diverged(metrics):
-                    rollbacks += 1
-                    if rollbacks > loop_cfg.max_rollbacks:
-                        raise RuntimeError(
-                            f"divergence at step {step}: rollback budget "
-                            f"({loop_cfg.max_rollbacks}) exhausted"
-                        )
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    if step in poisoned:
+                        with _span("train.batch"):
+                            _ = next(data_iter)  # consume and drop the poison batch
+                        log.warning("skipping poisoned batch at step %d", step)
+                        step += 1
+                        continue
+                    if fault_plan is not None:
+                        delay = fault_plan.step_delay(step)
+                        if delay:
+                            time.sleep(delay)
+                        scale = fault_plan.drift_scale(step)
+                        if scale is not None:
+                            params = (drift_apply or _default_drift)(params, scale)
+                            log.warning(
+                                "fault: drift_inject scale=%.4f at step %d", scale, step
+                            )
+                        if fault_plan.nan_grad(step):
+                            params = _poison_params(params)
+                            log.warning("fault: nan_grad at step %d", step)
+                    t0 = time.monotonic()
+                    with _span("train.batch"):
+                        batch = next(data_iter)
+                    with _span("train.dispatch"):
+                        params, opt_state, metrics = train_step(params, opt_state, batch)
+                    with _span("train.wait"):
+                        # lint-ok: block-in-loop deliberate per-step sync: the
+                        # straggler detector times wall-clock per step
+                        jax.block_until_ready(metrics["loss"])
+                    dt = time.monotonic() - t0
+                    with _span("train.report"):
+                        if loop_cfg.rollback and _diverged(metrics):
+                            rollbacks += 1
+                            if rollbacks > loop_cfg.max_rollbacks:
+                                raise RuntimeError(
+                                    f"divergence at step {step}: rollback budget "
+                                    f"({loop_cfg.max_rollbacks}) exhausted"
+                                )
+                            if pending_save is not None:
+                                pending_save.join()
+                                pending_save = None
+                            back_step, restored = ckpt.restore_latest(
+                                loop_cfg.checkpoint_dir, (params, opt_state)
+                            )
+                            if back_step is None:
+                                raise RuntimeError(
+                                    f"divergence at step {step} but no valid "
+                                    f"checkpoint to roll back to in "
+                                    f"{loop_cfg.checkpoint_dir!r}"
+                                )
+                            params, opt_state = restored
+                            poisoned.add(step)
+                            log.warning(
+                                "divergence at step %d: rolled back to step %d "
+                                "(rollback %d/%d); the poisoned batch will be "
+                                "skipped on replay",
+                                step, back_step, rollbacks, loop_cfg.max_rollbacks,
+                            )
+                            step = back_step
+                            data_iter.step = back_step
+                            times.clear()  # wall times across a rollback are junk
+                            continue
+                        times.append(dt)
+                        med = float(np.median(times))
+                        if len(times) >= 10 and dt > loop_cfg.straggler_factor * med:
+                            log.warning(
+                                "straggler: step %d took %.3fs (median %.3fs) — on a real "
+                                "pod this triggers slice health checks", step, dt, med,
+                            )
+                        step += 1
+                        if step % loop_cfg.log_every == 0 or step == loop_cfg.total_steps:
+                            snap = {k: float(v) for k, v in metrics.items()}
+                            snap["step_time_s"] = dt
+                            history.append((step, snap))
+                            if on_metrics:
+                                on_metrics(step, snap)
+                            log.info("step %d %s", step, snap)
+                    want_save = (
+                        loop_cfg.checkpoint_dir
+                        and (step % loop_cfg.save_every == 0 or guard.requested)
+                    )
+                    if want_save:
+                        with _span("train.checkpoint"):
+                            if pending_save is not None:
+                                pending_save.join()
+                            if not sync_saves and not guard.requested:
+                                pending_save = ckpt.save_async(
+                                    loop_cfg.checkpoint_dir, step, (params, opt_state),
+                                    keep_last=loop_cfg.keep_last,
+                                )
+                            else:
+                                _save_sync(step, (params, opt_state))
+                    if guard.requested:
+                        log.warning("exiting cleanly after preemption at step %d", step)
+                        break
+            # final checkpoint so a finished run is always resumable/servable
+            if loop_cfg.checkpoint_dir and step > start_step and not guard.requested:
+                with _span("train.checkpoint"):
                     if pending_save is not None:
                         pending_save.join()
                         pending_save = None
-                    back_step, restored = ckpt.restore_latest(
-                        loop_cfg.checkpoint_dir, (params, opt_state)
-                    )
-                    if back_step is None:
-                        raise RuntimeError(
-                            f"divergence at step {step} but no valid "
-                            f"checkpoint to roll back to in "
-                            f"{loop_cfg.checkpoint_dir!r}"
-                        )
-                    params, opt_state = restored
-                    poisoned.add(step)
-                    log.warning(
-                        "divergence at step %d: rolled back to step %d "
-                        "(rollback %d/%d); the poisoned batch will be "
-                        "skipped on replay",
-                        step, back_step, rollbacks, loop_cfg.max_rollbacks,
-                    )
-                    step = back_step
-                    data_iter.step = back_step
-                    times.clear()  # wall times across a rollback are junk
-                    continue
-                times.append(dt)
-                med = float(np.median(times))
-                if len(times) >= 10 and dt > loop_cfg.straggler_factor * med:
-                    log.warning(
-                        "straggler: step %d took %.3fs (median %.3fs) — on a real "
-                        "pod this triggers slice health checks", step, dt, med,
-                    )
-                step += 1
-                if step % loop_cfg.log_every == 0 or step == loop_cfg.total_steps:
-                    snap = {k: float(v) for k, v in metrics.items()}
-                    snap["step_time_s"] = dt
-                    history.append((step, snap))
-                    if on_metrics:
-                        on_metrics(step, snap)
-                    log.info("step %d %s", step, snap)
-                want_save = (
-                    loop_cfg.checkpoint_dir
-                    and (step % loop_cfg.save_every == 0 or guard.requested)
-                )
-                if want_save:
-                    if pending_save is not None:
-                        pending_save.join()
-                    if not sync_saves and not guard.requested:
-                        pending_save = ckpt.save_async(
-                            loop_cfg.checkpoint_dir, step, (params, opt_state),
-                            keep_last=loop_cfg.keep_last,
-                        )
-                    else:
-                        _save_sync(step, (params, opt_state))
-                if guard.requested:
-                    log.warning("exiting cleanly after preemption at step %d", step)
-                    break
-            # final checkpoint so a finished run is always resumable/servable
-            if loop_cfg.checkpoint_dir and step > start_step and not guard.requested:
-                if pending_save is not None:
-                    pending_save.join()
-                    pending_save = None
-                _save_sync(step, (params, opt_state))
+                    _save_sync(step, (params, opt_state))
         except Exception:
             # crash path: best-effort checkpoint so restart loses nothing
             if loop_cfg.checkpoint_dir:

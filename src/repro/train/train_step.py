@@ -5,7 +5,9 @@ The optimizer is the paper's technique made first-class: orthogonal leaves
 (any ``core.METHODS`` entry, POGO by default, VAdam base, fused-kernel
 option), everything else by AdamW. Microbatch gradient accumulation runs as
 a ``lax.scan`` so the grad all-reduce of microbatch *i* can overlap the
-compute of *i+1* under XLA's latency-hiding scheduler.
+compute of *i+1* under XLA's latency-hiding scheduler. The update runs
+under the named scope ``optimizer`` (and ``apply_updates``), so a profile
+tells it from the model's layers.
 """
 
 from __future__ import annotations
@@ -152,7 +154,8 @@ def make_train_step(cfg, train_cfg: TrainConfig, optimizer=None):
                 params, batch
             )
 
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optim.apply_updates(params, updates)
         # The in-graph StepHealth verdict of the constraint step — the
         # rollback policy in train/loop.py branches on it host-side, so it
