@@ -14,6 +14,10 @@ schedule for the hot path, not an advisory constraint), so the per-shard
 kernel sees its local batch and effective HBM bandwidth scales with
 device count.
 
+Whole-sequence attention's Pallas kernels run under
+:func:`shard_attention`, over batch and heads, for the same reason: a
+Mosaic kernel is never partitioned automatically.
+
 The mesh is process-global state set by launchers (dryrun/train/serve);
 when unset (unit tests, single-device smoke runs) the hints are no-ops.
 """
@@ -237,6 +241,26 @@ def shard_group_step_tp(fn, batch: int, n: int, out_kinds, *,
         )(*args)
 
     return wrapped, tname, twidth
+
+
+def shard_attention(fn, batch: int, kv_heads: int):
+    """Wrap ``fn(q, k, v) -> out`` over head-major operands ``(B, heads,
+    ...)`` in ``shard_map``: batch over the DP axes, heads over "model"
+    where it divides the KV heads (query heads then split in whole GQA
+    groups). A Pallas kernel cannot be partitioned automatically, so a
+    mesh refuses to lower it unwrapped. On a mesh axis that neither
+    dimension takes, each device computes its batch shard's attention
+    whole. Returns ``fn`` when no mesh is set."""
+    if _MESH is None:
+        return fn
+    axes = _batch_axes(_MESH, batch)
+    taken = axes if isinstance(axes, tuple) else (axes,)
+    width = _MESH.shape.get("model", 1)
+    heads = ("model" if width > 1 and "model" not in taken
+             and kv_heads % width == 0 else None)
+    spec = P(axes, heads)
+    return shard_map(fn, mesh=_MESH, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
 
 
 def activation(x: jax.Array, model_dim: Optional[int] = None) -> jax.Array:

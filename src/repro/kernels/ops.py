@@ -766,37 +766,25 @@ def flash_attention(
     q, k, v, *, causal: bool = True, window=None,
     block_q: int = 512, block_k: int = 512, interpret: bool | None = None,
 ):
-    """Fused flash-attention forward on (B, S, H, hd) GQA inputs.
+    """Flash attention of q (B, H, hd, Sq) over k, v (B, KV, hd, Sk):
+    head-major, sequence last. Differentiable: the Pallas forward and
+    backward kernels. GQA maps query head h to KV head h // (H // KV).
 
-    Flattens batch x heads, repeats KV heads for GQA, pads S to block
-    multiples (exact: padded keys are masked by seq_len), and dispatches to
-    the Pallas kernel. Forward-only — training keeps the checkpointed JAX
-    path; serving/prefill use this.
+    Blocks are clamped to the sequences (a power of two of at least 128),
+    and the sequences padded to whole blocks: the kernels mask keys past
+    Sk, and padded query columns are sliced off, so they take no cotangent.
     """
     if interpret is None:
         interpret = _interpret_default()
-    b, sq, h, hd = q.shape
-    kvh = k.shape[2]
-    groups = h // kvh
-    sk = k.shape[1]
+    sq, sk = q.shape[3], k.shape[3]
     block_q = min(block_q, max(128, 1 << (sq - 1).bit_length()))
     block_k = min(block_k, max(128, 1 << (sk - 1).bit_length()))
-    kr = jnp.repeat(k, groups, axis=2) if groups > 1 else k
-    vr = jnp.repeat(v, groups, axis=2) if groups > 1 else v
-    qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, hd)
-    kf = jnp.moveaxis(kr, 2, 1).reshape(b * h, sk, hd)
-    vf = jnp.moveaxis(vr, 2, 1).reshape(b * h, sk, hd)
-    pq = (-sq) % block_q
-    pk = (-sk) % block_k
-    # NOTE: seq_len inside the kernel masks the padded keys; padded queries
-    # produce garbage rows that are sliced off below.
-    qf = jnp.pad(qf, ((0, 0), (0, pq), (0, 0)))
-    kf = jnp.pad(kf, ((0, 0), (0, pk), (0, 0)))
-    vf = jnp.pad(vf, ((0, 0), (0, pk), (0, 0)))
-    # the kernel masks keys >= true sk via its seq_len argument
-    out = _fa.flash_attention_fwd(
-        qf, kf, vf, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
-    out = out[:, :sq].reshape(b, h, sq, hd)
-    return jnp.moveaxis(out, 1, 2)
+
+    def pad(x, block):
+        extra = (-x.shape[3]) % block
+        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, extra))) if extra else x
+
+    spec = _fa.Spec(causal=causal, window=window, seq_k=sk, block_q=block_q,
+                    block_k=block_k, interpret=interpret)
+    out = _fa.attention(pad(q, block_q), pad(k, block_k), pad(v, block_k), spec)
+    return out[..., :sq]

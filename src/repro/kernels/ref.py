@@ -393,23 +393,18 @@ def manifold_distance_ref(x: Array) -> Array:
     return jnp.sqrt(jnp.sum(r * r, axis=(-2, -1)))
 
 
-def flash_attention_fwd_ref(q, k, v, *, causal=True, window=None):
-    """Oracle for the flash-attention forward kernel. q,k,v: (BH, S, hd).
-    Keys beyond the (unpadded) length are assumed absent by masking with
-    seq_len = k.shape[1] (the kernel receives padded inputs)."""
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    hd = q.shape[-1]
-    s = jnp.einsum("bqh,bkh->bqk", qf, kf) * hd**-0.5
-    sq, sk = q.shape[1], k.shape[1]
-    q_pos = jnp.arange(sq)[:, None]
-    k_pos = jnp.arange(sk)[None, :]
+def attention_lse_ref(q, k, *, causal=True, window=None):
+    """Oracle of the flash-attention forward's residual: the log-sum-exp of
+    each query's visible scaled scores. q (B, Sq, H, hd), k (B, Sk, KV, hd)
+    with GQA (query head h reads KV head h // (H // KV)); (B, H, Sq) fp32."""
+    b, sq, h, hd = q.shape
+    sk, kvh = k.shape[1], k.shape[2]
+    kr = jnp.repeat(k, h // kvh, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kr) * hd**-0.5
+    q_pos, k_pos = jnp.arange(sq)[:, None], jnp.arange(sk)[None, :]
     mask = jnp.ones((sq, sk), bool)
     if causal:
         mask = mask & (k_pos <= q_pos)
     if window is not None:
         mask = mask & (k_pos > q_pos - window)
-    s = jnp.where(mask[None], s, -2.0**30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkh->bqh", p, vf).astype(q.dtype)
+    return jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)

@@ -6,9 +6,14 @@ d_model)`` — because those are exactly the paper's St(p, n) matrices
 orthogonal and POGO updates the whole ``(layers, H, p, n)`` stack in one
 fused call.
 
-Training/prefill uses a flash-style two-level chunked attention
-(``lax.scan`` over KV blocks with an online-softmax carry) so the peak
-activation is O(block_q x block_k) per head instead of O(S^2).
+Whole-sequence attention (training, prefill, encoders, cross attention)
+runs on a TPU as the Pallas flash kernels (``kernels/flash_attention.py``)
+in the layout the projections produce for them: head-major, sequence last,
+(B, H, hd, S); under a mesh, split over batch and heads by
+``shard_hints.shard_attention``. On other backends, and where analysis
+unrolls every block for ``cost_analysis``, it runs as ``_flash_attend``: a
+two-level chunked attention (``lax.scan`` over KV blocks with an
+online-softmax carry), the kernels' reference.
 """
 
 from __future__ import annotations
@@ -19,6 +24,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..distributed import shard_hints
+from ..kernels import ops
 from . import layers
 
 Array = jax.Array
@@ -80,10 +87,45 @@ def init_kv_cache(batch: int, cache_len: int, cfg, dtype) -> KVCache:
     )
 
 
-def _project(params, x, name):
+def _project(params, x, name, seq_last: bool = False):
+    """(B, S, H, hd), or (B, H, hd, S) with ``seq_last``, in x's dtype."""
     w = params[name].astype(x.dtype)  # (H, hd, d)
+    if seq_last:
+        # x's dtype straight from the MXU, which accumulates in fp32: a
+        # product kept in fp32 and cast after it made XLA copy Q, K, V
+        # (and, in the backward, dO) into the kernels' layout.
+        return jnp.einsum("bsd,hkd->bhks", x, w)
     out = jnp.einsum("bsd,hkd->bshk", x, w, preferred_element_type=jnp.float32)
     return out.astype(x.dtype)
+
+
+def _use_kernel(cfg) -> bool:
+    """Whole-sequence attention takes the Pallas kernels on a TPU, unless
+    analysis unrolls every block (``inner_unroll``) for ``cost_analysis``."""
+    return jax.default_backend() == "tpu" and not getattr(cfg, "inner_unroll", False)
+
+
+def _attend_sequence(params, x, memory, cfg, q_pos, k_pos, *, causal, window):
+    """Projections, rope, attention of x's queries over memory's keys and
+    values, and the output projection: (B, S, d) -> (B, S, d)."""
+    blocks = dict(block_q=getattr(cfg, "flash_block_q", 512),
+                  block_k=getattr(cfg, "flash_block_k", 512))
+    kernel = _use_kernel(cfg)
+    q = _project(params, x, "q_proj", kernel)
+    k = _project(params, memory, "k_proj", kernel)
+    v = _project(params, memory, "v_proj", kernel)
+    q = layers.rope(q, q_pos, cfg.rope_theta, seq_last=kernel)
+    k = layers.rope(k, k_pos, cfg.rope_theta, seq_last=kernel)
+    w_o = params["o_proj"].astype(x.dtype)  # (H, hd, d)
+    if kernel:
+        attend = functools.partial(ops.flash_attention, causal=causal,
+                                   window=window, **blocks)
+        out = shard_hints.shard_attention(attend, q.shape[0], k.shape[1])(q, k, v)
+        return jnp.einsum("bhks,hkd->bsd", out, w_o)
+    out = _flash_attend(q, k, v, causal=causal, window=window,
+                        unroll=getattr(cfg, "inner_unroll", False), **blocks)
+    y = jnp.einsum("bshk,hkd->bsd", out, w_o, preferred_element_type=jnp.float32)
+    return y.astype(x.dtype)
 
 
 def _flash_attend(
@@ -189,62 +231,56 @@ def attention_apply(
     over the cache.
     """
     b, s, d = x.shape
-    if positions is None:
-        if cache is not None:
-            positions = jnp.full((b, s), cache.index, jnp.int32) + jnp.arange(s)[None]
-        else:
+    if cache is None:
+        if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        y = _attend_sequence(params, x, x, cfg, positions, positions,
+                             causal=causal, window=window)
+        return y, None
+    if positions is None:
+        positions = jnp.full((b, s), cache.index, jnp.int32) + jnp.arange(s)[None]
     q = _project(params, x, "q_proj")  # (B, S, H, hd)
     k = _project(params, x, "k_proj")  # (B, S, KV, hd)
     v = _project(params, x, "v_proj")
     q = layers.rope(q, positions, cfg.rope_theta)
     k = layers.rope(k, positions, cfg.rope_theta)
 
-    if cache is None:
-        out = _flash_attend(
-            q, k, v, causal=causal, window=window,
-            block_q=getattr(cfg, "flash_block_q", 512),
-            block_k=getattr(cfg, "flash_block_k", 512),
-            unroll=getattr(cfg, "inner_unroll", False),
-        )
-        new_cache = None
+    cache_len = cache.k.shape[1]
+    write_pos = (
+        jnp.mod(cache.index, cache_len) if window is not None else cache.index
+    )
+    k_new = jax.lax.dynamic_update_slice(
+        cache.k, k.astype(cache.k.dtype), (0, write_pos, 0, 0)
+    )
+    v_new = jax.lax.dynamic_update_slice(
+        cache.v, v.astype(cache.v.dtype), (0, write_pos, 0, 0)
+    )
+    new_cache = KVCache(k=k_new, v=v_new, index=cache.index + s)
+    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    groups = h // kvh
+    qg = q.reshape(b, s, kvh, groups, hd)
+    scores = jnp.einsum(
+        "bskgh,btkh->bkgst", qg, k_new.astype(q.dtype),
+        preferred_element_type=jnp.float32,
+    ) * (hd**-0.5)
+    t_pos = jnp.arange(cache_len)[None, None, None, None, :]
+    q_pos = positions[:, None, None, :, None]
+    if window is not None:
+        # ring buffer: slot t holds absolute position computed from index
+        # absolute position of slot t: the most recent cache_len entries
+        newest = new_cache.index - 1
+        slot_age = jnp.mod(write_pos - t_pos, cache_len)
+        abs_pos = newest - slot_age  # may be negative for unwritten slots
+        valid = (abs_pos >= 0) & (abs_pos <= q_pos) & (abs_pos > q_pos - window)
     else:
-        cache_len = cache.k.shape[1]
-        write_pos = (
-            jnp.mod(cache.index, cache_len) if window is not None else cache.index
-        )
-        k_new = jax.lax.dynamic_update_slice(
-            cache.k, k.astype(cache.k.dtype), (0, write_pos, 0, 0)
-        )
-        v_new = jax.lax.dynamic_update_slice(
-            cache.v, v.astype(cache.v.dtype), (0, write_pos, 0, 0)
-        )
-        new_cache = KVCache(k=k_new, v=v_new, index=cache.index + s)
-        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        groups = h // kvh
-        qg = q.reshape(b, s, kvh, groups, hd)
-        scores = jnp.einsum(
-            "bskgh,btkh->bkgst", qg, k_new.astype(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * (hd**-0.5)
-        t_pos = jnp.arange(cache_len)[None, None, None, None, :]
-        q_pos = positions[:, None, None, :, None]
-        if window is not None:
-            # ring buffer: slot t holds absolute position computed from index
-            # absolute position of slot t: the most recent cache_len entries
-            newest = new_cache.index - 1
-            slot_age = jnp.mod(write_pos - t_pos, cache_len)
-            abs_pos = newest - slot_age  # may be negative for unwritten slots
-            valid = (abs_pos >= 0) & (abs_pos <= q_pos) & (abs_pos > q_pos - window)
-        else:
-            valid = (t_pos < new_cache.index) & (t_pos <= q_pos)
-        scores = jnp.where(valid, scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = jnp.einsum(
-            "bkgst,btkh->bskgh", probs, v_new.astype(v.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(x.dtype)
-        out = out.reshape(b, s, h, hd)
+        valid = (t_pos < new_cache.index) & (t_pos <= q_pos)
+    scores = jnp.where(valid, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum(
+        "bkgst,btkh->bskgh", probs, v_new.astype(v.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
+    out = out.reshape(b, s, h, hd)
 
     w_o = params["o_proj"].astype(x.dtype)  # (H, hd, d)
     y = jnp.einsum("bshk,hkd->bsd", out, w_o, preferred_element_type=jnp.float32)
@@ -330,17 +366,5 @@ def cross_attention_apply(params, x: Array, memory: Array, cfg):
     b, s, d = x.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     mem_pos = jnp.broadcast_to(jnp.arange(memory.shape[1])[None], (b, memory.shape[1]))
-    q = _project(params, x, "q_proj")
-    k = _project(params, memory, "k_proj")
-    v = _project(params, memory, "v_proj")
-    q = layers.rope(q, positions, cfg.rope_theta)
-    k = layers.rope(k, mem_pos, cfg.rope_theta)
-    out = _flash_attend(
-        q, k, v, causal=False, window=None,
-        block_q=getattr(cfg, "flash_block_q", 512),
-        block_k=getattr(cfg, "flash_block_k", 512),
-        unroll=getattr(cfg, "inner_unroll", False),
-    )
-    w_o = params["o_proj"].astype(x.dtype)
-    y = jnp.einsum("bshk,hkd->bsd", out, w_o, preferred_element_type=jnp.float32)
-    return y.astype(x.dtype)
+    return _attend_sequence(params, x, memory, cfg, positions, mem_pos,
+                            causal=False, window=None)

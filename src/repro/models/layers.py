@@ -88,18 +88,21 @@ def _mm(x: Array, w: Array) -> Array:
     return out.astype(x.dtype)
 
 
-def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
-    """Rotary embedding over the last dim of (..., seq, heads, head_dim)."""
-    head_dim = x.shape[-1]
-    half = head_dim // 2
+def rope(x: Array, positions: Array, theta: float = 10000.0,
+         seq_last: bool = False) -> Array:
+    """Rotary embedding over head_dim of (..., seq, heads, head_dim), or of
+    (..., heads, head_dim, seq) with ``seq_last``; positions (..., seq)."""
+    axis = -2 if seq_last else -1
+    half = x.shape[axis] // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, half)
-    cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
+    if seq_last:
+        angles = jnp.swapaxes(angles, -1, -2)[..., None, :, :]  # (..., 1, half, seq)
+    else:
+        angles = angles[..., None, :]  # broadcast over heads
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x1, x2 = jnp.split(x, 2, axis=axis)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=axis)
     return out.astype(x.dtype)
 
 

@@ -7,6 +7,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -933,3 +935,48 @@ def test_checkpoint_tp_restore_different_width(tmp_path):
     """
     _run(save_body, n_devices=8)
     _run(restore_body, n_devices=8)
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "granite-moe-1b-a400m"])
+def test_attention_kernels_under_a_mesh_match_one_device(arch):
+    """Whole-sequence attention's Pallas kernels (interpret mode) on a
+    (2, 2) mesh with the launcher's shardings: wrapped in ``shard_map`` over
+    batch, and over heads where the KV heads divide the model axis (granite
+    smoke, 2 KV heads) or not (smollm smoke, 1), they give the forward and
+    the gradients of the one-device reference path."""
+    _run(
+        f"""
+        from repro.configs import get_config
+        from repro.distributed import shard_hints, sharding
+        from repro.launch.mesh import make_test_mesh
+        from repro.models import attention
+
+        cfg = get_config("{arch}", smoke=True)
+        params = attention.init_attention(jax.random.PRNGKey(0), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(1), (4, 160, cfg.d_model),
+                              jnp.bfloat16)
+
+        def run(p, x):
+            y, _ = attention.attention_apply(p, x, cfg)
+            return jnp.sum(y.astype(jnp.float32) ** 2), y
+
+        grad = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)
+        want = jax.jit(grad)(params, x)
+
+        mesh = make_test_mesh(4)
+        assert dict(mesh.shape) == {{"data": 2, "model": 2}}
+        shard_hints.set_mesh(mesh)
+        attention._use_kernel = lambda cfg: True
+        params_s = jax.device_put(params, sharding.param_shardings(params, mesh))
+        x_s = jax.device_put(x, sharding.token_sharding(mesh, 4))
+        lowered = jax.jit(grad).lower(params_s, x_s)
+        assert "shard_map" in lowered.as_text() or "sdy.manual_computation" in lowered.as_text()
+        got = lowered.compile()(params_s, x_s)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            np.testing.assert_allclose(g, w, atol=3e-2 * np.max(np.abs(w)), rtol=3e-2)
+        shard_hints.set_mesh(None)
+        print("OK")
+        """,
+        n_devices=4,
+    )

@@ -10,7 +10,13 @@ training configs dispatch, for every plan the planner can pick there:
   * the tiled fused step at starcoder2-15b's (128, 6144), with 128 matrices
     (1.5 GiB per (B, p, n) fp32 operand, well inside one chip);
   * the two tensor-parallel stages at n_local = 3072 (6144 over TP = 2);
-  * Newton-Schulz at (640, 64, 1024).
+  * Newton-Schulz at (640, 64, 1024);
+  * the flash-attention forward, dQ and dK/dV kernels at the train cells'
+    shapes, smollm-360m (16 x 2048 tokens, 15 heads over 5 KV heads) and
+    granite-moe-1b-a400m (4 x 4096, 16 over 8), and at every other mask,
+    group size and head_dim the attention configs use, blocks of 512;
+  * the attention block, forward and backward, on a (2, 2) mesh with the
+    training launcher's shardings.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and test workers import every file.
@@ -22,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import autotune, ops
+from repro.kernels import flash_attention as fa
 from repro.kernels import fused_step as fs
 from repro.kernels import newton_schulz as ns
 
@@ -56,7 +63,8 @@ CASES = _cases()
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """A described v5e:2x2 topology."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
         from jax.experimental import topologies
@@ -74,8 +82,13 @@ def one_chip():
         except Exception as e:  # noqa: BLE001 - no TPU compiler here
             jax.config.update("jax_enable_compilation_cache", was)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
         jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(fn, shapes, sharding):
@@ -163,3 +176,73 @@ def test_fused_group_step_entry_compiles_for_v5e(one_chip, monkeypatch,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 1024**3
+
+
+# (B, H, KV, head_dim, Sq, Sk, causal, window): the train cells' attention
+# and that of the other attention configs: MQA with a 48-head group split
+# over the grid, sliding windows, head_dim 128 and 256, an encoder's
+# bidirectional attention and cross attention with Sq != Sk.
+ATTENTION = {
+    "smollm-360m": (16, 15, 5, 64, 2048, 2048, True, None),
+    "granite-moe-1b-a400m": (4, 16, 8, 64, 4096, 4096, True, None),
+    "granite-20b": (1, 48, 1, 128, 4096, 4096, True, None),
+    "mixtral-8x22b-window": (1, 48, 8, 128, 8192, 8192, True, 4096),
+    "recurrentgemma-2b-window": (1, 10, 1, 256, 4096, 4096, True, 2048),
+    "seamless-encoder": (2, 16, 16, 64, 1024, 1024, False, None),
+    "seamless-cross": (2, 16, 16, 64, 512, 1536, False, None),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("cell", list(ATTENTION))
+def test_flash_attention_kernels_compile_for_v5e(cell, kernel, one_chip):
+    b, h, kvh, hd, sq, sk, causal, window = ATTENTION[cell]
+    spec = fa.Spec(causal=causal, window=window, seq_k=sk, block_q=512, block_k=512)
+    q, kv = ((b, h, hd, sq), jnp.bfloat16), ((b, kvh, hd, sk), jnp.bfloat16)
+    row = ((b, h, 1, sq), F32)
+    if kernel == "fwd":
+        compiled = _compile(lambda q, k, v: fa._forward(q, k, v, spec),
+                            [q, kv, kv], one_chip)
+    else:
+        def backward(q, k, v, do, lse, d):
+            dq, dk, dv = fa._backward(q, k, v, do, lse, d, spec)
+            return dq if kernel == "dq" else (dk, dv)
+        compiled = _compile(backward, [q, kv, kv, q, row, row], one_chip)
+    # the name the trace gives the op, which attention_kernel_ms.train reads
+    assert f"flash_attention_{kernel}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "granite-moe-1b-a400m"])
+def test_attention_block_compiles_on_a_v5e_mesh(arch, v5e, monkeypatch):
+    """Attention's forward and backward at full width on a (2, 2) v5e mesh
+    with the training launcher's shardings: the kernels lower under
+    ``shard_map`` (a Mosaic kernel cannot be partitioned automatically),
+    with heads split over the model axis (granite, 8 KV heads) or not
+    (smollm, 5)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs import get_config
+    from repro.distributed import shard_hints, sharding
+    from repro.models import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("data", "model"))
+    monkeypatch.setattr(shard_hints, "_MESH", mesh)
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda: attention.init_attention(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, sharding.param_shardings(params, mesh))
+    x = jax.ShapeDtypeStruct((8, 2048, cfg.d_model), jnp.bfloat16,
+                             sharding=sharding.token_sharding(mesh, 8))
+
+    def loss(p, x):
+        y, _ = attention.attention_apply(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    text = compiled.as_text()
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"flash_attention_{kernel}" in text
