@@ -1462,7 +1462,8 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
                 post_scale=fused_base.post_scale, mu=mug,
             )
             if ef is None:
-                total = jax.lax.psum(payload, tp_ax[0])
+                with jax.named_scope("psum"):
+                    total = jax.lax.psum(payload, tp_ax[0])
                 ef2 = None
             else:
                 from ..distributed import compression
@@ -1758,7 +1759,8 @@ def _build(method: Method, cfg: OrthoConfig) -> GradientTransformation:
 
     # The driver's device work carries the named scopes ``ortho`` and, per
     # group, ``stack`` (gather and pad), ``step`` (the group step and its
-    # kernel) and ``unstack`` (crop, the update as a difference, scatter).
+    # kernel; on the DPxTP route ``step/psum``, the one payload psum) and
+    # ``unstack`` (crop, the update as a difference, scatter).
     def scoped_update(grads, state, params=None):
         with jax.named_scope("ortho"):
             return update(grads, state, params)

@@ -29,6 +29,70 @@ def _set_xla_flags(n_fake_devices: int | None):
         ]).strip()
 
 
+@dataclasses.dataclass(frozen=True)
+class Placement:
+    """Where the train step's arguments live on a mesh: a tree of
+    ``NamedSharding`` for the parameters and one for the optimizer state
+    (the launcher's 2-D rules, ``distributed.sharding``), and the sharding
+    of every (global_batch, seq) array of a batch."""
+
+    mesh: Any
+    params: Any
+    opt_state: Any
+    tokens: Any
+
+    def init(self, make_params, optimizer, key):
+        """``make_params(key)`` and the optimizer state, each made directly
+        under its shardings: no array is ever whole on one device."""
+        import jax
+
+        with jax.profiler.TraceAnnotation("train.place"):
+            params = jax.jit(make_params, out_shardings=self.params)(key)
+            opt_state = jax.jit(optimizer.init,
+                                out_shardings=self.opt_state)(params)
+        return params, opt_state
+
+    def jit_step(self, step_fn):
+        """The step jitted with the parameters and state donated and kept
+        in their shardings from one step to the next (the constraint
+        groups' layout is resharded back inside the step)."""
+        import jax
+
+        return jax.jit(
+            step_fn, donate_argnums=(0, 1),
+            in_shardings=(self.params, self.opt_state, self.tokens),
+            out_shardings=(self.params, self.opt_state, None))
+
+
+def place(param_shapes, optimizer, global_batch, *, devices=None,
+          multi_pod=False) -> Placement:
+    """Build the (data, model) mesh over ``devices`` (default: all), set it
+    for the model's sharding hints, and give the shardings of parameters
+    shaped like ``param_shapes``, of ``optimizer``'s state for them and
+    of a batch of ``global_batch`` rows."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ..distributed import shard_hints, sharding
+    from .mesh import make_test_mesh
+
+    with jax.profiler.TraceAnnotation("train.place"):
+        mesh = make_test_mesh(devices=devices, multi_pod=multi_pod)
+        # before the optimizer's state is shaped: its group plan reads the
+        # mesh's model axis
+        shard_hints.set_mesh(mesh)
+        o_specs = sharding.opt_state_specs(
+            jax.eval_shape(optimizer.init, param_shapes), param_shapes, mesh)
+        return Placement(
+            mesh=mesh,
+            params=sharding.param_shardings(param_shapes, mesh),
+            opt_state=jax.tree.map(
+                lambda s: NamedSharding(mesh, s), o_specs,
+                is_leaf=lambda x: isinstance(x, PartitionSpec)),
+            tokens=sharding.token_sharding(mesh, global_batch),
+        )
+
+
 @dataclasses.dataclass
 class TrainRun:
     params: Any
@@ -97,22 +161,12 @@ def run(argv=None) -> TrainRun:
     from .. import compile_cache, core
     from ..configs import get_config
     from ..data.pipeline import DataConfig, DataIterator
-    from ..distributed import shard_hints, sharding
     from ..models import ortho, transformer as tfm
     from ..train.loop import LoopConfig, train
     from ..train.train_step import TrainConfig, make_train_step
-    from .mesh import make_test_mesh
 
     compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = None
-    if args.mesh != "none":
-        mesh = make_test_mesh(multi_pod=args.mesh == "test-multipod")
-        shard_hints.set_mesh(mesh)
-
-    key = jax.random.PRNGKey(args.seed)
-    params = tfm.init_params(key, cfg)
-    params = ortho.project_init(params, cfg)
 
     import ast
 
@@ -140,19 +194,23 @@ def run(argv=None) -> TrainRun:
         ),
     )
     step_fn, optimizer = make_train_step(cfg, train_cfg)
-    opt_state = optimizer.init(params)
 
+    def make_params(key):
+        return ortho.project_init(tfm.init_params(key, cfg), cfg)
+
+    key = jax.random.PRNGKey(args.seed)
     token_sharding = None
-    if mesh is not None:
-        p_shard = sharding.param_shardings(params, mesh)
-        params = jax.device_put(params, p_shard)
-        o_specs = sharding.opt_state_specs(opt_state, params, mesh)
-        o_shard = jax.tree.map(
-            lambda s: jax.sharding.NamedSharding(mesh, s), o_specs,
-            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
-        )
-        opt_state = jax.device_put(opt_state, o_shard)
-        token_sharding = sharding.token_sharding(mesh, args.global_batch)
+    if args.mesh != "none":
+        placement = place(jax.eval_shape(make_params, key), optimizer,
+                          args.global_batch,
+                          multi_pod=args.mesh == "test-multipod")
+        params, opt_state = placement.init(make_params, optimizer, key)
+        token_sharding = placement.tokens
+        step_jit = placement.jit_step(step_fn)
+    else:
+        params = make_params(key)
+        opt_state = optimizer.init(params)
+        step_jit = jax.jit(step_fn, donate_argnums=(0, 1))
 
     data = DataIterator(
         DataConfig(
@@ -167,11 +225,7 @@ def run(argv=None) -> TrainRun:
     batch0 = next(data)
     data.step = 0  # step-indexed: the loop starts from the same batch
     t0 = time.perf_counter()
-    compiled = (
-        jax.jit(step_fn, donate_argnums=(0, 1))
-        .lower(params, opt_state, batch0)
-        .compile()
-    )
+    compiled = step_jit.lower(params, opt_state, batch0).compile()
     compile_seconds = time.perf_counter() - t0
     log.info("train step compiled in %.1f s", compile_seconds)
     loop_cfg = LoopConfig(
