@@ -1,6 +1,8 @@
 """Model-zoo behaviour: block correctness, decode==prefill consistency,
 recurrence oracles, MoE invariants."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -212,6 +214,110 @@ def test_moe_single_expert_equals_dense_mlp():
     up = x @ p["w_up"][0]
     ref = (jax.nn.silu(gate) * up) @ p["w_down"][0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-3, rtol=1e-3)
+
+
+def _moe_scatter(params, x, cfg, capacity_factor):
+    """The scatter formulation the gathers replaced, as an oracle: per row a
+    stable sort by expert, a scatter-add of the tokens into (E*cap + 1, d)
+    slots (the last one a sink for drops), and a scatter-add of the gated
+    expert outputs back to their tokens."""
+    b, s, d = x.shape
+    e, k = cfg.num_experts, cfg.num_experts_per_token
+    gates, idx = jax.lax.top_k(jax.nn.softmax(x @ params["router"], -1), k)
+    gates = gates / jnp.sum(gates, -1, keepdims=True)
+    cap = int(max(1, (s * k * capacity_factor) // e))
+
+    def row(xt, idx, gates):
+        flat = idx.reshape(s * k)
+        order = jnp.argsort(flat, stable=True)
+        expert, tok = flat[order], jnp.repeat(jnp.arange(s), k)[order]
+        pos = jnp.arange(s * k) - jnp.searchsorted(expert, jnp.arange(e))[expert]
+        dest = jnp.where(pos < cap, expert * cap + pos, e * cap)
+        slots = jnp.zeros((e * cap + 1, d)).at[dest].add(xt[tok])
+        slots = slots[:-1].reshape(e, cap, d)
+        h = (jax.nn.silu(jnp.einsum("ecd,edf->ecf", slots, params["w_gate"]))
+             * jnp.einsum("ecd,edf->ecf", slots, params["w_up"]))
+        out = jnp.einsum("ecf,efd->ecd", h, params["w_down"]).reshape(e * cap, d)
+        out = jnp.concatenate([out, jnp.zeros((1, d))])
+        w = gates.reshape(s * k)[order] * (pos < cap)
+        return jnp.zeros((s, d)).at[tok].add(out[dest] * w[:, None])
+
+    return jax.vmap(row)(x, idx, gates)
+
+
+@pytest.mark.parametrize("e,k,cf,skew", [(8, 2, 1.0, False), (8, 2, 4.0, False),
+                                         (4, 1, 1.0, True)],
+                         ids=["drops", "no_drops", "one_expert_takes_all"])
+def test_moe_gathers_match_scatter_oracle(e, k, cf, skew):
+    """Dispatch and combine as gathers equal the scatter formulation in the
+    output and in the gradients of x, the router and all three expert
+    weights, over batch rows (vmap), with and without drops, and with every
+    assignment routed to one expert."""
+    cfg = _cfg(block_pattern=("moe_attn",), num_experts=e,
+               num_experts_per_token=k, moe_d_ff=32)
+    p = moe.init_moe(KEY, cfg)
+    x = jax.random.normal(jax.random.PRNGKey(11), (3, 32, cfg.d_model))
+    if skew:  # expert 0's logit is at least 1, every other one 0
+        p["router"] = jnp.zeros_like(p["router"]).at[0, 0].set(1.0)
+        x = x.at[..., 0].set(1.0 + jnp.abs(x[..., 0]))
+    proj = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+
+    def loss(f):
+        return lambda p, x: jnp.sum(f(p, x) * proj)
+
+    new = lambda p, x: moe.moe_apply(p, x, cfg, capacity_factor=cf)[0]  # noqa: E731
+    old = lambda p, x: _moe_scatter(p, x, cfg, cf)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(new(p, x)), np.asarray(old(p, x)),
+                               rtol=1e-5, atol=1e-5)
+    g_new = jax.jit(jax.grad(loss(new), argnums=(0, 1)))(p, x)
+    g_old = jax.jit(jax.grad(loss(old), argnums=(0, 1)))(p, x)
+    for got, want in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    # the slots and the assignments are one partial permutation read both
+    # ways: the stable sort fills each expert in the token order that ranks
+    s = x.shape[1]
+    cap = int(max(1, (s * k * cf) // e))
+    idx = jax.lax.top_k(x[0] @ p["router"], k)[1]
+    inv, slot_src = (np.asarray(a).reshape(-1) for a in moe._slots(idx, e, cap))
+    filled, kept = np.flatnonzero(slot_src < s * k), np.flatnonzero(inv < e * cap)
+    np.testing.assert_array_equal(inv[slot_src[filled]], filled)
+    np.testing.assert_array_equal(slot_src[inv[kept]], kept)
+    load = np.bincount(np.asarray(idx).reshape(-1), minlength=e)
+    assert len(kept) == np.minimum(load, cap).sum()
+    if skew:
+        assert load[0] == s and len(kept) == cap
+
+
+def test_moe_gradient_gathers_without_d_wide_scatter():
+    """The compiled gradient of the layer under remat, as the train step
+    runs it, holds no scatter of d_model-wide rows (route's top_k transpose
+    over (B, S, E) is the only scatter), and every d-wide gather, the
+    backward's included, lies under moe/dispatch or moe/combine, which
+    moe_dispatch_ms.train reads."""
+    from bench.scopes import labels_of
+
+    cfg = _cfg(block_pattern=("moe_attn",), num_experts=8,
+               num_experts_per_token=2, moe_d_ff=32, moe_capacity_factor=1.0)
+    p = moe.init_moe(KEY, cfg)
+    x = jax.random.normal(jax.random.PRNGKey(13), (2, 16, cfg.d_model), jnp.bfloat16)
+
+    def loss(p, x):
+        with jax.named_scope("moe"):
+            y, aux = jax.checkpoint(lambda p, x: moe.moe_apply(p, x, cfg))(p, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
+    ops = re.findall(r"= \w+\[([\d,]*)\]\S* (scatter|gather)\(.*?op_name=\"([^\"]*)\"", text)
+    scatters = [dims for dims, op, _ in ops if op == "scatter"]
+    assert scatters and all(str(cfg.d_model) not in dims.split(",") for dims in scatters)
+    wide = [name for dims, op, name in ops
+            if op == "gather" and dims.split(",")[-1] == str(cfg.d_model)]
+    where = [labels_of(name) & {"moe/dispatch", "moe/combine"} for name in wide]
+    assert all(where)
+    backward = {lab for name, w in zip(wide, where) if "transpose(" in name for lab in w}
+    assert backward == {"moe/dispatch", "moe/combine"}
 
 
 # ------------------------------------------------------------------ enc-dec

@@ -246,3 +246,44 @@ def test_attention_block_compiles_on_a_v5e_mesh(arch, v5e, monkeypatch):
     text = compiled.as_text()
     for kernel in ("fwd", "dq", "dkv"):
         assert f"flash_attention_{kernel}" in text
+
+
+def test_moe_layer_compiles_on_a_v5e_mesh(v5e, monkeypatch):
+    """granite-moe-1b-a400m's MoE layer, forward and backward under remat,
+    at the 4-chip cell's shape (4 x 4096 tokens) on a (2, 2) v5e mesh with
+    the training launcher's shardings: dispatch and combine stay local to
+    each batch shard, so no collective lies under ``moe/dispatch`` or
+    ``moe/combine`` (a replicated dispatch buffer is the known failure)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from bench.scopes import labels_of
+    from repro.configs import get_config
+    from repro.distributed import shard_hints, sharding
+    from repro.models import moe
+
+    mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("data", "model"))
+    monkeypatch.setattr(shard_hints, "_MESH", mesh)
+    cfg = get_config("granite-moe-1b-a400m")
+    params = jax.eval_shape(lambda: {"ffn": moe.init_moe(jax.random.PRNGKey(0), cfg)})
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, sharding.param_shardings(params, mesh))
+    x = jax.ShapeDtypeStruct((4, 4096, cfg.d_model), jnp.bfloat16,
+                             sharding=sharding.token_sharding(mesh, 4))
+
+    def loss(p, x):
+        with jax.named_scope("moe"):
+            y, aux = jax.checkpoint(lambda p, x: moe.moe_apply(p["ffn"], x, cfg))(p, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    collectives = re.findall(
+        r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+        r"(?:-start)?\(.*?op_name=\"([^\"]*)\"", text)
+    assert collectives  # the experts' TP sums are there
+    routed = [name for _, name in collectives
+              if labels_of(name) & {"moe/dispatch", "moe/combine"}]
+    assert routed == []
